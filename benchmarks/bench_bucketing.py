@@ -31,7 +31,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import functools, json, time
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import types
 from repro.launch import hlo_cost
 from repro.train import bucketing
@@ -58,7 +57,7 @@ OUT_SPECS = {n: P() for n in SHAPES}
 
 
 def make(fn):
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(IN_SPECS, P()), out_specs=OUT_SPECS,
                        check_vma=False)
     def wrapped(xs, key):

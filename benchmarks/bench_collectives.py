@@ -46,7 +46,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, functools, json, re, time
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.configs import registry as cfg_registry
 from repro.core import collectives, types, wire
 from repro.launch import hlo_cost
@@ -79,7 +78,7 @@ res = {"schema": 1, "n": N, "d": D, "reps": REPS, "wire_dtype": "float32",
 xs = jax.random.normal(jax.random.PRNGKey(0), (N, D), jnp.float32) * 0.3
 key = jax.random.PRNGKey(1)
 for name, cfg in preset_cfgs().items():
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def f(x, k):
         return collectives.compressed_mean(x.reshape(D), k, cfg)
@@ -146,7 +145,7 @@ for name, cfg in preset_cfgs().items():
     entry = {"mean_us": res["presets"][name]["step_time_us"]}
     for f_, tag in ((1, "trim1_us"), (2, "trim2_us")):
         rcfg = dataclasses.replace(cfg, decode_policy=f"trim({f_})")
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("data"), P()), out_specs=P(),
                            check_vma=False)
         def f(x, k, rcfg=rcfg):
@@ -179,7 +178,6 @@ import dataclasses, functools, json, re, time
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro import compat
 from repro.configs import registry as cfg_registry
 from repro.core import collectives, wire
 
@@ -212,9 +210,9 @@ def cross_host_bytes(txt):
     return total
 
 def bench(cfg):
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(("pod", "data")), P()), out_specs=P(),
-                       check_vma=False, check_rep=False)
+                       check_vma=False)
     def f(x, k):
         return collectives.compressed_mean(x.reshape(D), k, cfg)
     fj = jax.jit(f)

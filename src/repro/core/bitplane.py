@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from repro.core import encoders
 from repro.core import types as t
 from repro.kernels.bernoulli_wire import ref as bw_ref
+from repro.kernels.numerics import count_prefix
 from repro.kernels.bitplane import ops as bp_ops
 
 WORD = 32
@@ -91,8 +92,10 @@ def floats_to_words(v, wire_dtype):
         return jax.lax.bitcast_convert_type(v, jnp.uint32)
     h = jax.lax.bitcast_convert_type(
         v.astype(wire_dtype), jnp.uint16).astype(jnp.uint32)
-    h = jnp.pad(h, (0, (-h.shape[0]) % 2)).reshape(-1, 2)
-    return h[:, 0] | (h[:, 1] << jnp.uint32(16))
+    h = jnp.pad(h, (0, (-h.shape[0]) % 2))
+    # strided halves, not a (m, 2) view: XLA:TPU compiles the relayout of
+    # a 2-wide minor dimension slowly at wire-buffer sizes.
+    return h[0::2] | (h[1::2] << jnp.uint32(16))
 
 
 def words_to_floats(w, count: int, wire_dtype):
@@ -100,10 +103,12 @@ def words_to_floats(w, count: int, wire_dtype):
     w = w.reshape(-1)
     if wire_bits(wire_dtype) == 32:
         return jax.lax.bitcast_convert_type(w, jnp.float32)[:count]
-    halves = jnp.stack([w & jnp.uint32(0xFFFF), w >> jnp.uint32(16)],
-                       axis=-1).reshape(-1)[:count]
+    # interleave by strided writes (see floats_to_words)
+    halves = (jnp.zeros((2 * w.shape[0],), jnp.uint16)
+              .at[0::2].set((w & jnp.uint32(0xFFFF)).astype(jnp.uint16))
+              .at[1::2].set((w >> jnp.uint32(16)).astype(jnp.uint16)))
     return jax.lax.bitcast_convert_type(
-        halves.astype(jnp.uint16), jnp.dtype(wire_dtype)).astype(jnp.float32)
+        halves[:count], jnp.dtype(wire_dtype)).astype(jnp.float32)
 
 
 def rank_scatter(values, sent, cap: int):
@@ -145,7 +150,7 @@ def topcap_mask(scores, cap: int):
     thr = jax.lax.fori_loop(0, 32, body, jnp.uint32(0))
     need_ties = cap - jnp.sum((bits > thr).astype(jnp.int32))
     is_tie = bits == thr
-    tie_rank = jnp.cumsum(is_tie.astype(jnp.int32))
+    tie_rank = count_prefix(is_tie)
     return (bits > thr) | (is_tie & (tie_rank <= need_ties))
 
 
@@ -245,7 +250,7 @@ def ternary_unpack(buf, d: int, cap: int, wire_dtype):
     sym = bp_ops.unpack_bits(buf[:pw], 2, d)
     vals = words_to_floats(buf[pw:pw + vw], cap, wire_dtype)
     c = words_to_floats(buf[pw + vw:], 2, wire_dtype)
-    pos = jnp.cumsum((sym == 2).astype(jnp.int32)) - 1
+    pos = count_prefix(sym == 2) - 1
     valid = (sym == 2) & (pos < cap)
     v = vals[jnp.clip(pos, 0, cap - 1)]
     fallback = 0.5 * (c[0] + c[1])  # symmetric 6σ-overflow substitute
@@ -339,7 +344,7 @@ def ternary_decode_shard(rows, syms, prior, d: int, cap: int, wire_dtype,
     def body(i, acc):
         sym = syms[i]
         sent = sym == 2
-        pos = prior[i] + jnp.cumsum(sent.astype(jnp.int32)) - 1
+        pos = prior[i] + count_prefix(sent) - 1
         valid = sent & (pos < cap)
         v = vals[i][jnp.clip(pos, 0, cap - 1)]
         fallback = 0.5 * (c[i, 0] + c[i, 1])

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 
 from repro.core import centers as centers_lib
 from repro.core import types as t
+from repro.kernels import numerics
+from repro.kernels.threefry import ref as threefry
 
 
 class Encoded(NamedTuple):
@@ -60,7 +62,7 @@ def encode_bernoulli(key, x, probs, mu) -> Encoded:
     """
     x = jnp.asarray(x)
     probs = jnp.broadcast_to(jnp.asarray(probs, x.dtype), x.shape)
-    u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
+    u = threefry.uniform(key, x.shape)
     sent = u < probs  # P(sent) = p_j; p_j == 0 -> never sent.
     psafe = jnp.where(probs > 0, probs, 1.0)
     scaled = x / psafe - (1.0 - psafe) / psafe * mu
@@ -81,7 +83,7 @@ def sample_support(key, d: int, k: int) -> jax.Array:
     §4.4: on SPMD hardware every peer can regenerate the subset from the
     shared key, so indices never travel on the wire.
     """
-    g = jax.random.gumbel(key, (d,))
+    g = threefry.gumbel(key, (d,))
     _, idx = jax.lax.top_k(g, k)
     return jnp.sort(idx)
 
@@ -118,8 +120,13 @@ def encode_binary(key, x) -> Encoded:
     vmin = jnp.min(x)
     vmax = jnp.max(x)
     delta = vmax - vmin
-    p = jnp.where(delta > 0, (x - vmin) / jnp.where(delta > 0, delta, 1.0), 0.0)
-    u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
+    # div_rn: the IEEE quotient on every backend, so the branch bits (the
+    # wire plane) do not depend on the device that packs them.
+    p = jnp.where(delta > 0,
+                  numerics.div_rn(x - vmin, jnp.where(delta > 0, delta, 1.0)
+                                  ).astype(x.dtype),
+                  0.0)
+    u = threefry.uniform(key, x.shape)
     take_max = u < p
     y = jnp.where(take_max, vmax, vmin)
     return Encoded(y=y, mu=vmin, support=take_max,
@@ -141,10 +148,13 @@ def encode_ternary(key, x, p1, p2, c1, c2) -> Encoded:
     x = jnp.asarray(x)
     p1 = jnp.broadcast_to(jnp.asarray(p1, x.dtype), x.shape)
     p2 = jnp.broadcast_to(jnp.asarray(p2, x.dtype), x.shape)
-    u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
+    u = threefry.uniform(key, x.shape)
     rest = 1.0 - p1 - p2
     restsafe = jnp.where(rest > 0, rest, 1.0)
-    y_rest = (x - p1 * c1 - p2 * c2) / restsafe
+    # each product fused into its subtraction with one rounding, as
+    # XLA:CPU contracts them; fma_rn gives these bits on every backend.
+    num = numerics.fma_rn(-p2, c2, numerics.fma_rn(-p1, c1, x))
+    y_rest = numerics.div_rn(num, restsafe).astype(x.dtype)
     y = jnp.where(u < p1, c1, jnp.where(u < p1 + p2, c2, y_rest))
     sent = u >= p1 + p2  # the full-precision branch
     # branch index (0 → c1, 1 → c2, 2 → pass-through): the symbol the

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import centers as centers_lib
 from repro.core import mse as mse_lib
+from repro.kernels import numerics
 
 
 def optimal_probs(xs, mus, B: float, iters: int = 64):
@@ -119,7 +120,9 @@ def ternary_optimal_probs(x, q, c1=None, c2=None):
         c2 = jnp.max(x)
     s = jnp.clip(x - q * (c1 + c2) / 2, (1.0 - q) * c1, (1.0 - q) * c2)
     span = c2 - c1
-    p1 = jnp.where(span > 0, ((1.0 - q) * c2 - s) / jnp.where(span > 0, span, 1.0),
+    p1 = jnp.where(span > 0,
+                   numerics.div_rn((1.0 - q) * c2 - s,
+                                   jnp.where(span > 0, span, 1.0)),
                    1.0 - q)  # degenerate constant vector: all mass on c1
     p1 = jnp.broadcast_to(p1, x.shape)
     return p1, (1.0 - q) - p1

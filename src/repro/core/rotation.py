@@ -24,8 +24,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.hadamard import ops as hadamard_ops
+from repro.kernels.threefry import ref as threefry
 
 # Domain tag for deriving the shared per-bucket rotation seed from the
 # per-step key: distinct from the node ranks (0..n-1) and bucket indices
@@ -57,7 +59,16 @@ def _pad(x, dp: int):
 
 def rademacher_diag(key, d: int, dtype=jnp.float32):
     """The D of Q = (1/√d)HD: iid ±1 signs from a shared seed."""
-    return jax.random.rademacher(key, (d,), dtype=dtype)
+    return threefry.rademacher(key, (d,), dtype)
+
+
+def inv_scale(c: int) -> float:
+    """1/√c as the f32 the normalization multiplies by.
+
+    Computed on the host: XLA folds ``x / √c`` with a constant c into this
+    multiply anyway, and a host constant keeps the rounding of √c off the
+    device (the TPU's sqrt is not correctly rounded)."""
+    return float(np.float32(1.0) / np.sqrt(np.float32(c)))
 
 
 def _chunked_fwht(x):
@@ -76,7 +87,7 @@ def rotate(key, x):
     dp = xp.shape[-1]
     signs = rademacher_diag(key, dp, xp.dtype)
     z, c = _chunked_fwht(xp * signs)
-    return z / jnp.sqrt(jnp.asarray(c, xp.dtype))
+    return z * jnp.asarray(inv_scale(c), xp.dtype)
 
 
 def unrotate(key, z, d: int):
@@ -84,5 +95,5 @@ def unrotate(key, z, d: int):
     dp = z.shape[-1]
     signs = rademacher_diag(key, dp, z.dtype)
     h, c = _chunked_fwht(z)
-    x = signs * h / jnp.sqrt(jnp.asarray(c, z.dtype))
+    x = signs * h * jnp.asarray(inv_scale(c), z.dtype)
     return x[..., :d]

@@ -52,9 +52,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import types as t
 from repro.core.wire import robust
+from repro.kernels import numerics
 
 Axes = Tuple[str, ...]
 
@@ -64,8 +64,8 @@ def axis_rank_size(axes: Axes):
     rank = jnp.zeros((), jnp.int32)
     n = 1
     for ax in axes:
-        rank = rank * compat.axis_size(ax) + jax.lax.axis_index(ax)
-        n *= compat.axis_size(ax)
+        rank = rank * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
+        n *= jax.lax.axis_size(ax)
     return rank, n
 
 
@@ -157,7 +157,7 @@ def center(x, policy: str):
     if policy == "zero":
         return jnp.zeros((), jnp.float32)
     if policy == "mean":
-        return jnp.mean(x).astype(jnp.float32)
+        return numerics.ordered_mean(x)
     if policy == "min":
         return jnp.min(x).astype(jnp.float32)
     raise ValueError(f"center policy {policy!r} not supported on the wire "

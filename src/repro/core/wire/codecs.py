@@ -34,7 +34,10 @@ from repro.core import encoders
 from repro.core import types as t
 from repro.core.wire import base
 from repro.kernels.bernoulli_wire import ops as bw_ops
+from repro.kernels.bernoulli_wire import ref as bw_ref
+from repro.kernels import numerics
 from repro.kernels.fixed_k_encode import ops as fk
+from repro.kernels.threefry import ref as threefry
 
 
 def _wire_r(cfg: t.CompressionConfig) -> int:
@@ -136,7 +139,7 @@ class FixedKGatherCodec(base.WireCodec):
 
         acc = jax.lax.fori_loop(0, n, body,
                                 jnp.zeros((nb, fk.BLOCK), jnp.float32))
-        return (acc / n + jnp.mean(all_mu)).reshape(-1)[:d]
+        return (acc / n + numerics.ordered_mean(all_mu)).reshape(-1)[:d]
 
     def decode_gathered_shard(self, rows, key, cfg, d, n, shard, nshards):
         # reduce-scatter decomposition: accumulate only the blocks in this
@@ -160,7 +163,7 @@ class FixedKGatherCodec(base.WireCodec):
 
         acc = jax.lax.fori_loop(0, n, body,
                                 jnp.zeros((nb_s + 1, fk.BLOCK), jnp.float32))
-        return (acc[:nb_s] / n + jnp.mean(all_mu)).reshape(-1)
+        return (acc[:nb_s] / n + numerics.ordered_mean(all_mu)).reshape(-1)
 
     def scatter_bits(self, n, d, cfg):
         # flat scatter (DESIGN.md §12) adds ONE collective on the main
@@ -238,8 +241,7 @@ def bernoulli_wire_slots(d: int, fraction: float) -> int:
 def _bernoulli_support(key, d: int, p):
     """The S_i of Eq. (1) under uniform probs: data-independent, so any peer
     regenerates it from the shared per-step key + node index alone."""
-    u = jax.random.uniform(key, (d,), dtype=jnp.float32)
-    return u < p
+    return threefry.uniform(key, d) < p
 
 
 def bernoulli_pack(flat, key, p: float, cap: int, mu, *, scaled=True):
@@ -264,7 +266,7 @@ def bernoulli_pack(flat, key, p: float, cap: int, mu, *, scaled=True):
 def bernoulli_unpack(buf, key, p: float, cap: int, mu, d: int):
     """Regenerate node ``key``'s support and reconstruct its dense Y_i."""
     sent = _bernoulli_support(key, d, p)
-    pos = jnp.cumsum(sent.astype(jnp.int32)) - 1
+    pos = numerics.count_prefix(sent) - 1
     valid = sent & (pos < cap)
     vals = buf[jnp.clip(pos, 0, cap - 1)]
     return jnp.where(valid, vals, mu)

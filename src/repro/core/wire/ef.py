@@ -67,6 +67,7 @@ from repro.core import bitplane
 from repro.core import encoders
 from repro.core import rotation
 from repro.core.wire import base, codecs, rotated
+from repro.kernels import numerics
 
 
 # --------------------------------------------------------------------------- #
@@ -87,16 +88,19 @@ def _two_means(v, select=None):
     if select is None:
         select = jnp.ones(v.shape, bool)
     cnt = jnp.maximum(jnp.sum(select.astype(jnp.float32)), 1.0)
-    thr = jnp.sum(jnp.where(select, v, 0.0)) / cnt
+    thr = numerics.div_rn(numerics.ordered_sum(jnp.where(select, v, 0.0)),
+                          cnt)
     hi = v >= thr
     n_hi = jnp.sum((select & hi).astype(jnp.float32))
     n_lo = jnp.sum((select & ~hi).astype(jnp.float32))
     c_hi = jnp.where(n_hi > 0,
-                     jnp.sum(jnp.where(select & hi, v, 0.0))
-                     / jnp.maximum(n_hi, 1.0), thr)
+                     numerics.div_rn(numerics.ordered_sum(
+                         jnp.where(select & hi, v, 0.0)),
+                         jnp.maximum(n_hi, 1.0)), thr)
     c_lo = jnp.where(n_lo > 0,
-                     jnp.sum(jnp.where(select & ~hi, v, 0.0))
-                     / jnp.maximum(n_lo, 1.0), thr)
+                     numerics.div_rn(numerics.ordered_sum(
+                         jnp.where(select & ~hi, v, 0.0)),
+                         jnp.maximum(n_lo, 1.0)), thr)
     return c_lo, c_hi, hi
 
 
@@ -149,7 +153,7 @@ def _ternary_twin(flat, cap, cfg):
     """
     d = flat.shape[0]
     cap = min(cap, d)
-    dev = jnp.abs(flat - jnp.mean(flat))
+    dev = jnp.abs(flat - numerics.ordered_mean(flat))
     # same membership as top_k(dev, cap) (ties → lowest index) but via the
     # O(d)-per-pass bit bisection — top_k was the ef_ternary pack hot spot.
     passm = bitplane.topcap_mask(dev, cap)
@@ -175,7 +179,7 @@ def _dense_twin(flat, key, rank, cfg):
     if kind == "ternary":
         d = flat.shape[0]
         k = max(1, min(d, int(round(float(cfg.encoder.fraction) * d))))
-        dev = jnp.abs(flat - jnp.mean(flat))
+        dev = jnp.abs(flat - numerics.ordered_mean(flat))
         _, top = jax.lax.top_k(dev, k)
         passm = jnp.zeros((d,), bool).at[top].set(True)
         c_lo, c_hi, hi = _two_means(flat, select=~passm)

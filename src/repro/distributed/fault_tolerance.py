@@ -42,6 +42,7 @@ from repro.core.wire import codecs as wire_codecs
 from repro.core.wire import ef as wire_ef
 from repro.core.wire import resolve as wire_resolve
 from repro.core.wire import rotated as wire_rotated
+from repro.kernels.numerics import count_prefix
 from repro.kernels.fixed_k_encode import ops as fk
 
 
@@ -151,8 +152,8 @@ class ReplaySupport:
 def _bernoulli_replay(cfg, kenc, dim: int) -> ReplaySupport:
     p = float(cfg.encoder.fraction)
     cap = comm_cost.bernoulli_capacity(dim, p)
-    sent = jax.random.uniform(kenc, (dim,), dtype=jnp.float32) < p
-    pos = jnp.cumsum(sent.astype(jnp.int32)) - 1
+    sent = wire_codecs._bernoulli_support(kenc, dim, p)
+    pos = count_prefix(sent) - 1
     kept = sent & (pos < cap)
     slot = jnp.where(kept, pos, -1)
     return ReplaySupport(dim=dim, support=sent, kept=kept, slot=slot)

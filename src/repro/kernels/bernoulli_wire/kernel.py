@@ -116,12 +116,54 @@ def _rank_window(carry, incl, sent, cap: int):
     return keep, row_start, local
 
 
-def _onehot(local, mask):
-    """(BM, WIN) f32 selector: row k has a single 1 at column local[k]
-    when mask[k], else all zeros."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (BM, WIN), 1)
-    return ((local.reshape(BM, 1) == cols)
-            & mask.reshape(BM, 1)).astype(jnp.float32)
+def _row_selectors(local, mask):
+    """Split each kept coordinate's window slot into (window row, lane).
+
+    For source row r the kernels need ``sel_row[r]`` — (WIN_ROWS, LANES)
+    masks [slot row == wr] — and ``sel_lane[r]`` — (LANES, LANES) masks
+    [slot lane == wl] laid out with the source lane on the minor axis.
+    Both are built by broadcasting one (1, LANES) source row against an
+    iota, so no lane<->sublane reshape is needed.
+    """
+    wrow = local // LANES
+    wlane = local % LANES
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (WIN_ROWS, LANES), 0)
+    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    sel_row, sel_lane = [], []
+    for r in range(BM_ROWS):
+        keep_r = mask[r:r + 1, :]
+        sel_row.append((row_iota == wrow[r:r + 1, :]) & keep_r)
+        sel_lane.append((lane_iota == wlane[r:r + 1, :]).astype(jnp.float32))
+    return sel_row, sel_lane
+
+
+def _scatter_window(vals, local, mask):
+    """(WIN_ROWS, LANES) window with vals[r, l] at slot local[r, l] where
+    mask.  Per source row: a (WIN_ROWS, LANES) row-select times the
+    transposed (LANES, LANES) lane one-hot on the MXU.  Every slot gets
+    at most one nonzero product, so the f32 result is exact."""
+    sel_row, sel_lane = _row_selectors(local, mask)
+    out = jnp.zeros((WIN_ROWS, LANES), jnp.float32)
+    for r in range(BM_ROWS):
+        a = jnp.where(sel_row[r], vals[r:r + 1, :], 0.0)
+        out = out + jax.lax.dot_general(
+            a, sel_lane[r], (((1,), (1,)), ((), ())), precision=_HIGHEST)
+    return out
+
+
+def _gather_window(window, local, mask):
+    """(BM_ROWS, LANES) with window[slot local[r, l]] where mask, else 0 —
+    the inverse of :func:`_scatter_window`: one MXU lane-gather per source
+    row, then a masked sublane sum that has a single nonzero term."""
+    sel_row, sel_lane = _row_selectors(local, mask)
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (BM_ROWS, LANES), 0)
+    out = jnp.zeros((BM_ROWS, LANES), jnp.float32)
+    for r in range(BM_ROWS):
+        picked = jax.lax.dot(window, sel_lane[r], precision=_HIGHEST)
+        v = jnp.sum(jnp.where(sel_row[r], picked, 0.0), axis=0,
+                    keepdims=True)
+        out = jnp.where(row_of == r, v, out)
+    return out
 
 
 def _encode_kernel(key_ref, par_ref, x_ref, o_ref, carry_ref, *,
@@ -148,11 +190,9 @@ def _encode_kernel(key_ref, par_ref, x_ref, o_ref, carry_ref, *,
     incl = _flat_cumsum(sent)
     keep, row_start, local = _rank_window(carry, incl, sent, cap)
 
-    contrib = jax.lax.dot(vals.reshape(1, BM), _onehot(local, keep),
-                          precision=_HIGHEST)
+    contrib = _scatter_window(vals, local, keep)
     win = o_ref[pl.ds(row_start, WIN_ROWS), :]
-    o_ref[pl.ds(row_start, WIN_ROWS), :] = (
-        win + contrib.reshape(WIN_ROWS, LANES))
+    o_ref[pl.ds(row_start, WIN_ROWS), :] = win + contrib
     carry_ref[0] = carry + incl[BM_ROWS - 1, LANES - 1]
 
 
@@ -208,9 +248,8 @@ def _decode_kernel(keys_ref, mus_ref, par_ref, buf_ref, o_ref, carry_ref, *,
     incl = _flat_cumsum(sent)
     valid, row_start, local = _rank_window(carry, incl, sent, cap)
 
-    window = buf_ref[0, pl.ds(row_start, WIN_ROWS), :].reshape(WIN, 1)
-    vals = jax.lax.dot(_onehot(local, valid), window,
-                       precision=_HIGHEST).reshape(BM_ROWS, LANES)
+    window = buf_ref[0, pl.ds(row_start, WIN_ROWS), :]
+    vals = _gather_window(window, local, valid)
     mu = mus_ref[i]
     recon = jnp.where(mask, jnp.where(valid, vals, mu), 0.0)
 
@@ -286,9 +325,8 @@ def _decode_shard_kernel(keys_ref, mus_ref, par_ref, prior_ref, off_ref,
     incl = _flat_cumsum(sent)
     valid, row_start, local = _rank_window(carry, incl, sent, cap)
 
-    window = buf_ref[0, pl.ds(row_start, WIN_ROWS), :].reshape(WIN, 1)
-    vals = jax.lax.dot(_onehot(local, valid), window,
-                       precision=_HIGHEST).reshape(BM_ROWS, LANES)
+    window = buf_ref[0, pl.ds(row_start, WIN_ROWS), :]
+    vals = _gather_window(window, local, valid)
     mu = mus_ref[i]
     recon = jnp.where(inblock, jnp.where(valid, vals, mu), 0.0)
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.numerics import PREFIX_GROUP, count_prefix
 from repro.kernels.threefry import ref as tf_ref
 
 
@@ -45,7 +46,7 @@ def rank_select(values, sent, cap: int):
     the CPU backend (gathers vectorize; d-wide scatters do not).
     """
     d = values.shape[0]
-    cum = jnp.cumsum(sent.astype(jnp.int32))
+    cum = count_prefix(sent)
     src = jnp.searchsorted(cum, jnp.arange(1, cap + 1, dtype=jnp.int32),
                            side="left")
     filled = jnp.arange(cap, dtype=jnp.int32) < cum[-1]
@@ -61,8 +62,7 @@ def encode(flat, key, p: float, cap: int, mu, *, scaled: bool = True):
     capacity-padded compaction.
     """
     d = flat.shape[0]
-    u = jax.random.uniform(key, (d,), dtype=jnp.float32)
-    sent = u < p
+    sent = tf_ref.uniform(key, d) < p
     vals = flat / p - (1.0 - p) / p * mu if scaled else flat
     return rank_select(vals, sent, cap)
 
@@ -72,9 +72,8 @@ def decode_one(buf, key, p: float, cap: int, mu, d: int):
 
     Exactly repro.core.wire.codecs.bernoulli_unpack's op chain.
     """
-    u = jax.random.uniform(key, (d,), dtype=jnp.float32)
-    sent = u < p
-    pos = jnp.cumsum(sent.astype(jnp.int32)) - 1
+    sent = tf_ref.uniform(key, d) < p
+    pos = count_prefix(sent) - 1
     valid = sent & (pos < cap)
     vals = buf[jnp.clip(pos, 0, cap - 1)]
     return jnp.where(valid, vals, mu)
@@ -85,33 +84,6 @@ def decode_one(buf, key, p: float, cap: int, mu, d: int):
 # (n, TILE) working set stays cache-resident instead of materializing the
 # full (n, d) uniform matrix the historical vmap decode built.
 DECODE_TILE = 8192
-# Group width of the matmul cumsum: rows reshape to (·, L) and one
-# (L, L)-triangular f32 matmul yields the within-group inclusive counts.
-_CUMSUM_GROUP = 32
-
-
-def _cumsum_rows(sent):
-    """Inclusive int32 cumsum along axis 1 of an (n, T) bool matrix.
-
-    Expressed as one f32 matmul against a triangular ones matrix per
-    :data:`_CUMSUM_GROUP`-wide group plus a cheap group-prefix add — the
-    XLA CPU int32 cumsum lowers to a serial scan, the matmul vectorizes
-    (~2× the decode-shard wall-clock).  Exact because the f32 partial sums
-    count 0/1 lanes and never exceed T ≤ 2²⁴; rows longer than that (or
-    not group-aligned) fall back to the plain scan.
-    """
-    n, tl = sent.shape
-    grp = _CUMSUM_GROUP
-    if tl % grp or tl > (1 << 24):
-        return jnp.cumsum(sent.astype(jnp.int32), axis=1)
-    g = sent.reshape(n, tl // grp, grp).astype(jnp.float32)
-    tri = jnp.triu(jnp.ones((grp, grp), jnp.float32))
-    within = jax.lax.dot_general(
-        g, tri, (((2,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST)
-    totals = within[:, :, -1]
-    prefix = jnp.cumsum(totals, axis=1) - totals
-    return (within + prefix[:, :, None]).reshape(n, tl).astype(jnp.int32)
 
 
 def _peer_sum(recon):
@@ -150,8 +122,7 @@ def decode_sum(bufs, mus, keys, p: float, cap: int, d: int):
     working set instead of (n, d).
     """
     n = bufs.shape[0]
-    grp = _CUMSUM_GROUP
-    tile = min(DECODE_TILE, -(-d // grp) * grp)
+    tile = min(DECODE_TILE, -(-d // PREFIX_GROUP) * PREFIX_GROUP)
     nt = -(-d // tile)
 
     def body(ti, carry):
@@ -162,7 +133,7 @@ def decode_sum(bufs, mus, keys, p: float, cap: int, d: int):
         idxc = jnp.where(real, idx, 0)
         u = jax.vmap(lambda k: tf_ref.uniform_at(k, idxc, d))(keys)
         sent = (u < p) & real[None, :]
-        incl = _cumsum_rows(sent)
+        incl = jax.vmap(count_prefix)(sent)
         pos = prior[:, None] + incl - 1
         valid = sent & (pos < cap)
         vals = jnp.take_along_axis(bufs, jnp.clip(pos, 0, cap - 1), axis=1)
@@ -205,7 +176,7 @@ def decode_sum_shard(bufs, mus, sent, prior, cap: int):
     cap fall back to μ.  Padding lanes (sent False) also decode to μ and
     must be truncated by the caller.
     """
-    pos = prior[:, None] + _cumsum_rows(sent) - 1
+    pos = prior[:, None] + jax.vmap(count_prefix)(sent) - 1
     valid = sent & (pos < cap)
     vals = jnp.take_along_axis(bufs, jnp.clip(pos, 0, cap - 1), axis=1)
     recon = jnp.where(valid, vals, mus[:, None])

@@ -19,19 +19,65 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
-BM_PACK = 256    # (256, 128) u32 in -> (256, 128*w/32) u32 out
+BM_PACK = 256    # (256, 128) u32 in -> (256*w/32, 128) u32 out
 BM_UNPACK = 8    # (8, 128) u32 words in -> (8, 128*32/w) u32 out
 BM_ACCUM = 8     # (n, 8, 128) u32 words -> (8, 128*32) f32 accumulator
 
 
-def _pack_kernel(width, v_ref, o_ref):
+def pack_fields(v, width: int):
+    """(R, 128) symbols (< 2**width, any int dtype) -> (R·w/32, 128) uint32
+    words: the ref.py word stream of the block, row-major and lane-dense.
+
+    Word c of source row r holds lanes [c·per, (c+1)·per) of that row at
+    bit offsets w·(lane % per), per = 32/w.  The fields are disjoint, so a
+    word is the plain sum of ``symbol · 2**offset``, split into a low
+    (offsets < 16) and a high half so every partial sum is an integer
+    below 2**16 — exact in f32 on the MXU:
+
+    * ``v @ F`` (F: 128 × 128, column j packs the fields of word j % n_w,
+      n_w = 128/per words per row) puts word j % n_w of row r at lane j;
+    * a select keeps lane j only in row r with r % per == j // n_w, and
+      ``G @ ·`` (G: rows/per × rows, G[q, r] = [r // per == q]) folds each
+      group of ``per`` rows into one 128-lane output row.
+
+    Every output element is one nonzero product, so the result is exact.
+    The output is lane-dense: flattening it gives the word stream with no
+    relayout (a (R, 128/per) output made XLA spend minutes compiling the
+    reshape at 2^24 coordinates).  (Mosaic cannot split the lane axis or
+    reduce uint32, which the direct formulation needs.)
+    """
     per = 32 // width
+    n_w = LANES // per
+    rows = v.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    shift = width * (lane % per)
+    mine = lane // per == col % n_w
+    f_lo = jnp.where(mine & (shift < 16), jnp.left_shift(1, shift), 0)
+    f_hi = jnp.where(mine & (shift >= 16), jnp.left_shift(1, shift - 16), 0)
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    keep = r % per == j // n_w
+    q = jax.lax.broadcasted_iota(jnp.int32, (rows // per, rows), 0)
+    rr = jax.lax.broadcasted_iota(jnp.int32, (rows // per, rows), 1)
+    fold = (rr // per == q).astype(jnp.float32)
+    vf = v.astype(jnp.int32).astype(jnp.float32)
+
+    def half(f):
+        spread = jax.lax.dot(vf, f.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+        spread = jnp.where(keep, spread, 0.0)
+        return jax.lax.dot(fold, spread,
+                           precision=jax.lax.Precision.HIGHEST
+                           ).astype(jnp.int32)
+
+    words = jnp.left_shift(half(f_hi), 16) | half(f_lo)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+
+def _pack_kernel(width, v_ref, o_ref):
     v = v_ref[...].astype(jnp.uint32) & jnp.uint32((1 << width) - 1)
-    bm, bn = v.shape
-    v3 = v.reshape(bm, bn // per, per)
-    shifts = (jax.lax.broadcasted_iota(jnp.uint32, (1, 1, per), 2)
-              * jnp.uint32(width))
-    o_ref[...] = jnp.sum(v3 << shifts, axis=-1, dtype=jnp.uint32)
+    o_ref[...] = pack_fields(v, width)
 
 
 def _unpack_kernel(width, w_ref, o_ref):
@@ -46,16 +92,17 @@ def _unpack_kernel(width, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
 def pack_bits_2d(vals, width: int, *, interpret: bool = False):
-    """vals: (R, 128) uint32 symbols, R % BM_PACK == 0 -> (R, 128*w/32)."""
+    """vals: (R, 128) uint32 symbols, R % BM_PACK == 0 -> (R·w/32, 128)
+    uint32 words, row-major in the ref.py word order."""
     r, c = vals.shape
     assert c == LANES and r % BM_PACK == 0, (r, c)
-    out_lanes = LANES * width // 32
+    out_rows = BM_PACK * width // 32
     return pl.pallas_call(
         functools.partial(_pack_kernel, width),
         grid=(r // BM_PACK,),
         in_specs=[pl.BlockSpec((BM_PACK, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((BM_PACK, out_lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, out_lanes), jnp.uint32),
+        out_specs=pl.BlockSpec((out_rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r * width // 32, LANES), jnp.uint32),
         interpret=interpret,
     )(vals)
 

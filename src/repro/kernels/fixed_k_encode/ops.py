@@ -4,12 +4,15 @@ k is expressed in *blocks* (kb) of ref.BLOCK coordinates; the flat input is
 zero-padded to a BLOCK multiple (padding joins the population like real
 coordinates — harmless: its deviations are (0 − μ), reconstructed exactly
 as μ-centred noise that is sliced away before use).
+
+Backend policy: repro.kernels.backend (resolved once at import, like every
+other dispatcher) — TPU → the Pallas gather kernel, elsewhere → jnp.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.fixed_k_encode import fixed_k_encode as _kernel
 from repro.kernels.fixed_k_encode import ref as _ref
 from repro.kernels.fixed_k_encode.ref import sample_blocks  # noqa: F401  (re-export)
@@ -27,7 +30,7 @@ def fixed_k_encode(x, block_ids, mu, *, scale=None, force_pallas: bool = False):
     ``scale=None`` uses the unbiased d/k rescale of Eq. (4); ``scale=1.0``
     gives the *contractive* (biased) sparsifier used by error feedback.
     """
-    on_tpu = jax.default_backend() == "tpu"
+    use_pallas, interpret = backend.choose(force_pallas)
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     flat = jnp.pad(flat, (0, (-n) % BLOCK))
@@ -35,13 +38,13 @@ def fixed_k_encode(x, block_ids, mu, *, scale=None, force_pallas: bool = False):
     k = block_ids.shape[0] * BLOCK
     if scale is None:
         scale = d / k
-    if not (on_tpu or force_pallas):
+    if not use_pallas:
         blocks = flat.reshape(-1, BLOCK)[block_ids]
         return scale * (blocks - jnp.asarray(mu, jnp.float32))
     scal = jnp.stack([jnp.asarray(scale, jnp.float32),
                       jnp.asarray(mu, jnp.float32)]).reshape(1, 2)
     x3 = flat.reshape(-1, _kernel.ROWS, _kernel.BS)
-    out = _kernel.fixed_k_gather_2d(x3, block_ids, scal, interpret=not on_tpu)
+    out = _kernel.fixed_k_gather_2d(x3, block_ids, scal, interpret=interpret)
     return out.reshape(-1, BLOCK)
 
 

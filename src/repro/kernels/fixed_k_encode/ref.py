@@ -19,12 +19,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.threefry import ref as threefry
+
 BLOCK = 1024
 
 
 def sample_blocks(key, num_blocks: int, kb: int):
     """Uniform kb-subset of block ids (Gumbel top-k), sorted."""
-    g = jax.random.gumbel(key, (num_blocks,))
+    g = threefry.gumbel(key, (num_blocks,))
     _, ids = jax.lax.top_k(g, kb)
     return jnp.sort(ids)
 

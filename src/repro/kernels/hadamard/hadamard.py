@@ -1,21 +1,27 @@
-"""Pallas TPU kernel for the Walsh–Hadamard transform.
+"""Pallas TPU kernels for the Walsh–Hadamard transform (butterfly order).
 
-Hardware adaptation (DESIGN.md §2): the textbook butterfly is a strided
-VPU/reshape workload that maps poorly onto TPU (8,128) tiles.  We instead
-use the Kronecker factorization
+The kernels run the classic radix-2 butterfly of :mod:`.ref` — stage by
+stage, stride 1, 2, 4, …, with ``x[i] + x[i+h]`` in the lower and
+``x[i-h] − x[i]`` in the upper half of every 2h-group — so every output is
+the same chain of f32 adds as the CPU path and the transform is
+bit-identical on every backend.  That is what keeps the rotated wire
+formats (DESIGN.md §7) backend-independent: a matmul formulation
+(H_{d1} ⊗ H_{d2} on the MXU) sums in the MXU's own order and differs from
+the butterfly in the last bits of nearly every coordinate.
 
-    H_d = H_{d1} ⊗ H_{d2},   d = d1·d2
-    fwht(x) = H_{d1} @ X @ H_{d2},   X = x.reshape(d1, d2)
+Layout: a length-c vector (c a power of two, c ≥ 1024) is viewed row-major
+as (c/128, 128).  Strides below 128 pair lanes (``pltpu.roll`` along the
+lanes), strides below the tile height pair rows (roll along the sublanes);
+both rolls stay inside one VMEM tile.  Strides of a whole tile or more pair
+tiles with each other and run in a second kernel whose block stacks the
+same rows of every tile.  Each element's butterfly partner is found by
+rolling in both directions and selecting on the stride bit, exactly as in
+the jnp oracle.
 
-which turns the transform into two MXU matmuls per vector — O(d·(d1+d2))
-MACs instead of O(d log d) adds, a winning trade on a 197-TFLOP/s MXU vs a
-~4-TFLOP/s VPU, and with perfectly contiguous (lane-aligned) memory access.
-The H factors are *generated in-kernel* from iota + popcount parity, so no
-HBM traffic is spent on them.
-
-Grid: one program per batch row; each program holds X (d1, d2), H_{d1} and
-H_{d2} in VMEM.  Supported sizes: d1, d2 ≤ 1024 (⇒ d ≤ 2²⁰ per call; larger
-vectors are chunked by the caller — see kernels/hadamard/ops.py).
+Fused extras for the rotation (repro.kernels.rotated_encode): an optional
+±1 sign vector multiplied in before the first stage, an optional
+``inv_scale`` multiplied in after the last, and per-block (min, max)
+partials of the result.
 """
 from __future__ import annotations
 
@@ -24,42 +30,144 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+MIN_D = 8 * LANES        # one (8, 128) f32 tile
+TILE_ROWS = 512          # pass-1 block: (512, 128) f32 = 256 KiB
+CROSS_ROWS = 64          # pass-2 block: (tiles, 64, 128)
 
 
-def _hadamard_in_kernel(d: int, dtype):
-    """Materialize H_d inside the kernel from 2-D iota (TPU needs ≥2-D)."""
-    i = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
-    v = i & j
-    parity = jnp.zeros_like(v)
-    for s in range(10):  # d ≤ 1024 ⇒ 10 bits
-        parity = parity ^ ((v >> s) & 1)
-    return (1 - 2 * parity).astype(dtype)
+def _butterfly(x, h: int, axis: int):
+    """One radix-2 stage at stride h along ``axis`` (h < extent)."""
+    n = x.shape[axis]
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    below = pltpu.roll(x, h, axis)        # below[i] = x[i − h]
+    above = pltpu.roll(x, n - h, axis)    # above[i] = x[i + h]
+    return jnp.where((pos & h) != 0, below - x, x + above)
 
 
-def _fwht_kernel(x_ref, o_ref, *, d1: int, d2: int):
-    x = x_ref[0]  # (d1, d2)
-    acc_dtype = jnp.float32
-    h1 = _hadamard_in_kernel(d1, acc_dtype)
-    h2 = _hadamard_in_kernel(d2, acc_dtype)
-    t = jax.lax.dot(x.astype(acc_dtype), h2,
-                    precision=jax.lax.Precision.HIGHEST)
-    y = jax.lax.dot(h1, t, precision=jax.lax.Precision.HIGHEST)
-    o_ref[0] = y.astype(o_ref.dtype)
+def _tile_stages(x):
+    """All stages with stride < rows·128 on a row-major (rows, 128) tile."""
+    h = 1
+    while h < LANES:
+        x = _butterfly(x, h, 1)
+        h *= 2
+    h = 1
+    while h < x.shape[0]:
+        x = _butterfly(x, h, 0)
+        h *= 2
+    return x
 
 
-@functools.partial(jax.jit, static_argnames=("d1", "d2", "interpret"))
-def fwht_pallas(x, *, d1: int, d2: int, interpret: bool = False):
-    """x: (B, d1*d2) -> (B, d1*d2), unnormalized Walsh–Hadamard transform."""
-    b, d = x.shape
-    assert d == d1 * d2, (d, d1, d2)
-    x3 = x.reshape(b, d1, d2)
-    out = pl.pallas_call(
-        functools.partial(_fwht_kernel, d1=d1, d2=d2),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, d1, d2), x.dtype),
+def _minmax(lo, hi):
+    """(2, 128) block: row 0 = min(lo), row 1 = max(hi), broadcast."""
+    mn = jnp.min(jnp.min(lo, axis=0, keepdims=True), axis=1, keepdims=True)
+    mx = jnp.max(jnp.max(hi, axis=0, keepdims=True), axis=1, keepdims=True)
+    row = jax.lax.broadcasted_iota(jnp.int32, (2, LANES), 0)
+    return jnp.where(row == 0, mn, mx)
+
+
+def _tile_kernel(*refs, signed: bool, last: bool, inv_scale, with_mm: bool):
+    x_ref = refs[0]
+    s_ref = refs[1] if signed else None
+    o_ref = refs[1 + signed]
+    x = x_ref[...].astype(jnp.float32)
+    if signed:
+        x = x * s_ref[...]
+    z = _tile_stages(x)
+    if last and inv_scale is not None:
+        z = z * jnp.float32(inv_scale)
+    o_ref[...] = z
+    if last and with_mm:
+        refs[2 + signed][0] = _minmax(z, z)
+
+
+def _cross_kernel(x_ref, *out_refs, inv_scale, with_mm: bool):
+    o_ref = out_refs[0]
+    tiles = x_ref.shape[1]
+    parts = [x_ref[0, i] for i in range(tiles)]
+    h = 1
+    while h < tiles:
+        parts = [parts[i - h] - parts[i] if i & h else parts[i] + parts[i + h]
+                 for i in range(tiles)]
+        h *= 2
+    if inv_scale is not None:
+        parts = [p * jnp.float32(inv_scale) for p in parts]
+    for i in range(tiles):
+        o_ref[0, i] = parts[i]
+    if with_mm:
+        out_refs[1][0] = _minmax(functools.reduce(jnp.minimum, parts),
+                                 functools.reduce(jnp.maximum, parts))
+
+
+@functools.partial(jax.jit, static_argnames=("inv_scale", "with_minmax",
+                                             "interpret"))
+def fwht_pallas(x, signs=None, *, inv_scale=None, with_minmax: bool = False,
+                interpret: bool = False):
+    """Unnormalized Walsh–Hadamard transform of each row of x: (B, c).
+
+    c must be a power of two ≥ :data:`MIN_D`.  ``signs`` (B, c) is
+    multiplied in first and ``inv_scale`` (a Python float, applied as an
+    f32 multiply) last.  Returns the (B, c) f32 transform, plus the
+    (B, 2) per-row (min, max) when ``with_minmax``.
+    """
+    b, c = x.shape
+    assert c >= MIN_D and c & (c - 1) == 0, c
+    rows = c // LANES
+    tr = min(rows, TILE_ROWS)
+    tiles = rows // tr
+    single = tiles == 1
+    ntile = b * tiles
+    x2 = x.reshape(b * rows, LANES)
+    ins = [x2]
+    in_specs = [pl.BlockSpec((tr, LANES), lambda i: (i, 0))]
+    if signs is not None:
+        ins.append(signs.reshape(b * rows, LANES).astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((tr, LANES), lambda i: (i, 0)))
+    out_shape = [jax.ShapeDtypeStruct((b * rows, LANES), jnp.float32)]
+    out_specs = [pl.BlockSpec((tr, LANES), lambda i: (i, 0))]
+    mm_tile = single and with_minmax
+    if mm_tile:
+        out_shape.append(jax.ShapeDtypeStruct((ntile, 2, LANES), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 2, LANES), lambda i: (i, 0, 0)))
+    outs = pl.pallas_call(
+        functools.partial(_tile_kernel, signed=signs is not None,
+                          last=single, inv_scale=inv_scale,
+                          with_mm=mm_tile),
+        grid=(ntile,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(x3)
-    return out.reshape(b, d)
+    )(*ins)
+    y, mm = outs[0], (outs[1] if mm_tile else None)
+    if not single:
+        cr = min(tr, CROSS_ROWS)
+        ncol = tr // cr
+        y4 = y.reshape(b, tiles, tr, LANES)
+        spec = pl.BlockSpec((1, tiles, cr, LANES), lambda i, j: (i, 0, j, 0))
+        out_shape = [jax.ShapeDtypeStruct(y4.shape, jnp.float32)]
+        out_specs = [spec]
+        if with_minmax:
+            out_shape.append(
+                jax.ShapeDtypeStruct((b * ncol, 2, LANES), jnp.float32))
+            out_specs.append(pl.BlockSpec(
+                (1, 2, LANES), lambda i, j: (i * ncol + j, 0, 0)))
+        outs = pl.pallas_call(
+            functools.partial(_cross_kernel, inv_scale=inv_scale,
+                              with_mm=with_minmax),
+            grid=(b, ncol),
+            in_specs=[spec],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(y4)
+        y = outs[0]
+        mm = outs[1] if with_minmax else None
+    y = y.reshape(b, c)
+    if not with_minmax:
+        return y
+    part = mm[:, :, 0].reshape(b, -1, 2)
+    return y, jnp.stack([part[:, :, 0].min(axis=1),
+                         part[:, :, 1].max(axis=1)], axis=1)

@@ -1,11 +1,11 @@
 """Public jit'd wrapper for the Walsh–Hadamard transform.
 
-Dispatch policy:
-* TPU backend        → Pallas MXU kernel (kron-factorized, hadamard.py).
-* CPU / other        → Pallas kernel in interpret mode for small sizes in
-  tests, but by default the pure-jnp oracle (ref.py) — identical results,
-  no interpreter overhead.  The kernel is the TPU *target*; correctness is
-  guaranteed by the allclose sweeps in tests/test_kernel_hadamard.py.
+Dispatch (repro.kernels.backend, resolved once at import):
+* TPU backend        → Pallas butterfly kernel (hadamard.py);
+* CPU / other        → the pure-jnp butterfly (ref.py).
+Both run the same f32 add chain, so the results are bit-identical
+(tests/test_kernels.py; chip_smoke.py on the chip).  Rows shorter than one
+(8, 128) tile (hadamard.MIN_D) always take the jnp path.
 
 Vectors longer than MAX_D are processed in independent MAX_D chunks (a
 block-diagonal rotation; standard bucketing — see DESIGN.md §2).
@@ -19,14 +19,7 @@ from repro.kernels.hadamard import ref as _ref
 MAX_D = 1 << 20
 
 
-def _factorize(d: int):
-    """Split d = d1·d2 with d1, d2 powers of two, as square as possible."""
-    lg = d.bit_length() - 1
-    l1 = lg // 2
-    return 1 << l1, 1 << (lg - l1)
-
-
-def fwht(x, *, force_pallas: bool = False, interpret: bool | None = None):
+def fwht(x, *, force_pallas: bool = False):
     """Unnormalized Walsh–Hadamard transform along the last axis.
 
     x: (..., d) with d a power of two, d ≤ 2**20.
@@ -36,16 +29,9 @@ def fwht(x, *, force_pallas: bool = False, interpret: bool | None = None):
         raise ValueError(f"fwht needs power-of-two length, got {d}")
     if d > MAX_D:
         raise ValueError(f"fwht supports d ≤ {MAX_D}; chunk the input "
-                         "(repro.core.compression handles this)")
-    use_pallas, auto_interpret = backend.choose(force_pallas)
-    if not use_pallas:
+                         "(repro.core.rotation handles this)")
+    use_pallas, interpret = backend.choose(force_pallas)
+    if not use_pallas or d < _kernel.MIN_D:
         return _ref.fwht(x)
-    if interpret is None:
-        interpret = auto_interpret
-    shape = x.shape
-    x2 = x.reshape(-1, d)
-    if d < 4:  # degenerate sizes: oracle
-        return _ref.fwht(x).reshape(shape)
-    d1, d2 = _factorize(d)
-    return _kernel.fwht_pallas(x2, d1=d1, d2=d2,
-                               interpret=interpret).reshape(shape)
+    y = _kernel.fwht_pallas(x.reshape(-1, d), interpret=interpret)
+    return y.reshape(x.shape).astype(x.dtype)

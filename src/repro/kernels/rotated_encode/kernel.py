@@ -3,30 +3,28 @@
 The pre-fusion TPU path made four HBM round trips per bucket: the FWHT
 kernel wrote z, XLA re-read z for min/max, re-read it again for the
 stochastic threshold (with a separate d-wide uniform tensor), and the
-bit-plane pack kernel re-read the dense bits.  The fused pair makes two:
+bit-plane pack kernel re-read the dense bits.  The fused path makes two:
 
-* ``rotate_minmax_pallas`` — per MAX_D chunk, one kernel applies the
-  Rademacher signs, runs the Kronecker-factorized FWHT (two MXU matmuls
-  with the H factors generated in-kernel from iota parity — the
-  kernels/hadamard hardware adaptation), folds in the 1/√c scale, and
-  emits (min, max) partials alongside z — so the bracket scalars cost no
-  extra pass;
+* the rotation — :func:`repro.kernels.hadamard.hadamard.fwht_pallas` with
+  the Rademacher signs multiplied in before the butterfly, the 1/√c scale
+  after it, and (min, max) partials emitted alongside z — so the bracket
+  scalars cost no extra pass;
 
 * ``encode_pack_pallas`` — one kernel turns z into wire words: the
-  take-max probabilities, the Threefry branch draw
-  (repro.kernels.threefry.ref inlined, bit-exact with
-  ``jax.random.uniform``), and the 1-bit plane packing all happen
-  in-register per (256, 128) block, writing only the packed words.
+  take-max probabilities (the correctly rounded quotient of
+  repro.kernels.numerics), the Threefry branch draw
+  (repro.kernels.threefry.ref inlined), and the 1-bit plane packing all
+  happen in-register per (256, 128) block, writing only the packed words.
 
 Global (vmin, vmax) needs all chunks' partials, so the two kernels cannot
 merge for multi-chunk buckets (dp > MAX_D, block-diagonal Q) — the partial
 reduce between them is a (nchunks, 2) jnp min/max, order-free and exact.
 
-Oracle contract: bit-identical to repro.kernels.rotated_encode.ref in
-interpret mode (tests/test_rotated_encode_kernel.py).  The oracle uses the
-same Kronecker formulation as the TPU hadamard kernel — NOT the CPU
-butterfly — so CPU production bytes (golden) are out of scope by design;
-see ref.py.
+Oracle contract: the butterfly FWHT and the correctly rounded threshold
+make every step bit-identical to the CPU production chain
+(rotation.rotate → bitplane.binary_pack), so the fused path emits the
+golden wire bytes (tests/test_rotated_encode_kernel.py, interpret mode;
+chip_smoke.py on the chip).
 """
 from __future__ import annotations
 
@@ -38,47 +36,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bernoulli_wire import kernel as bw_kernel
-from repro.kernels.hadamard.hadamard import _hadamard_in_kernel
+from repro.kernels.bitplane.bitplane import pack_fields
+from repro.kernels.numerics import div_rn
 
 LANES = 128
-PACK_ROWS = 256            # (256, 128) coords -> (256, 4) u32 words per step
-_HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def _rotate_kernel(x_ref, s_ref, z_ref, mm_ref, *, d1: int, d2: int,
-                   scale: float):
-    xs = (x_ref[0] * s_ref[0]).astype(jnp.float32)
-    h1 = _hadamard_in_kernel(d1, jnp.float32)
-    h2 = _hadamard_in_kernel(d2, jnp.float32)
-    t = jax.lax.dot(xs, h2, precision=_HIGHEST)
-    y = jax.lax.dot(h1, t, precision=_HIGHEST)
-    z = y / jnp.float32(scale)
-    z_ref[0] = z
-    mm_ref[...] = jnp.stack([jnp.min(z), jnp.max(z)]).reshape(1, 2)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("d1", "d2", "scale", "interpret"))
-def rotate_minmax_pallas(x2, signs2, *, d1: int, d2: int, scale: float,
-                         interpret: bool = False):
-    """x2, signs2: (B, d1·d2) -> (z2 (B, d1·d2) f32, mm (B, 2) f32) with
-    mm[i] = (min, max) of chunk i after signs, FWHT and 1/scale."""
-    b, c = x2.shape
-    assert c == d1 * d2, (c, d1, d2)
-    x3 = x2.reshape(b, d1, d2)
-    s3 = signs2.reshape(b, d1, d2)
-    z3, mm = pl.pallas_call(
-        functools.partial(_rotate_kernel, d1=d1, d2=d2, scale=scale),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, 2), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, d1, d2), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 2), jnp.float32)],
-        interpret=interpret,
-    )(x3, s3)
-    return z3.reshape(b, c), mm
+PACK_ROWS = 256            # (256, 128) coords -> (8, 128) u32 words per step
 
 
 def _encode_pack_kernel(key_ref, par_ref, z_ref, o_ref, *, dp: int):
@@ -88,14 +50,11 @@ def _encode_pack_kernel(key_ref, par_ref, z_ref, o_ref, *, dp: int):
     vmin = par_ref[0]
     delta = par_ref[1] - vmin
     z = z_ref[...]
-    # encode_binary's guarded threshold, elementwise — delta is traced on
-    # both kernel and oracle sides, so the division rounds identically.
+    # encode_binary's guarded threshold, elementwise, with the same
+    # correctly rounded quotient (div_rn) as the CPU path.
     p = jnp.where(delta > 0,
-                  (z - vmin) / jnp.where(delta > 0, delta, 1.0), 0.0)
-    bits = (mask & (u < p)).astype(jnp.uint32)
-    v3 = bits.reshape(PACK_ROWS, LANES // 32, 32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-    o_ref[...] = jnp.sum(v3 << shifts, axis=-1, dtype=jnp.uint32)
+                  div_rn(z - vmin, jnp.where(delta > 0, delta, 1.0)), 0.0)
+    o_ref[...] = pack_fields((mask & (u < p)).astype(jnp.int32), 1)
 
 
 @functools.partial(jax.jit, static_argnames=("dp", "interpret"))
@@ -114,14 +73,14 @@ def encode_pack_pallas(z, key, vmin, vmax, *, dp: int,
         num_scalar_prefetch=2,
         grid=(rows // PACK_ROWS,),
         in_specs=[pl.BlockSpec((PACK_ROWS, LANES), lambda i, *_: (i, 0))],
-        out_specs=pl.BlockSpec((PACK_ROWS, LANES // 32),
+        out_specs=pl.BlockSpec((PACK_ROWS // 32, LANES),
                                lambda i, *_: (i, 0)),
         scratch_shapes=[],
     )
     words = pl.pallas_call(
         functools.partial(_encode_pack_kernel, dp=dp),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, LANES // 32), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((rows // 32, LANES), jnp.uint32),
         interpret=interpret,
     )(key, params, z2)
     return words.reshape(-1)[:-(-dp // 32)]

@@ -1,66 +1,23 @@
-"""jnp oracles for the fused §7.2 rotate + 1-bit encode kernels.
+"""jnp oracle for the fused §7.2 1-bit encode kernel.
 
-Two oracles, one per kernel in repro.kernels.rotated_encode.kernel:
-
-* :func:`rotate_minmax` — the Kronecker-matmul FWHT (H_{d1} ⊗ H_{d2} as
-  two MXU matmuls) with the Rademacher signs and 1/√c scale folded in,
-  plus per-chunk (min, max) partials.  NOTE this is deliberately the
-  TPU formulation (kernels/hadamard/hadamard.py), NOT the CPU butterfly in
-  kernels/hadamard/ref.py: the two differ in f32 rounding, and the fused
-  kernel replaces the TPU path.  The CPU production path
-  (rotation.rotate → bitplane.binary_pack) is untouched, so the golden
-  wire bytes — generated on CPU — never see either kernel.
-
-* :func:`binary_plane` — the §4.5 stochastic 1-bit plane for a rotated
-  vector given the global (vmin, vmax): exactly encode_binary's branch
-  draw (same Threefry stream via repro.kernels.threefry.ref, same
-  guarded-delta threshold ops) packed into uint32 words by the
-  kernels/bitplane reference layout.
+:func:`binary_plane` — the §4.5 stochastic 1-bit plane for a rotated
+vector given the global (vmin, vmax): exactly encode_binary's branch draw
+(same Threefry stream via repro.kernels.threefry.ref, same guarded,
+correctly rounded threshold) packed into uint32 words by the
+kernels/bitplane reference layout.  The rotation itself needs no separate
+oracle: the FWHT kernel runs the CPU butterfly's exact add chain
+(repro.kernels.hadamard.hadamard).
 
 Kernel↔oracle equivalence is exact (interpret mode, CPU), pinned by
 tests/test_rotated_encode_kernel.py.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.bitplane import ref as bp_ref
+from repro.kernels.numerics import div_rn
 from repro.kernels.threefry import ref as tref
-
-_HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def hadamard_matrix(m: int):
-    """H_m as f32 from iota + popcount parity — the same construction the
-    kernels materialize in VMEM (m ≤ 1024 ⇒ 10 parity bits)."""
-    i = jnp.arange(m, dtype=jnp.int32)
-    v = i[:, None] & i[None, :]
-    parity = jnp.zeros_like(v)
-    for s in range(10):
-        parity = parity ^ ((v >> s) & 1)
-    return (1 - 2 * parity).astype(jnp.float32)
-
-
-def rotate_minmax(x2, signs2, *, d1: int, d2: int, scale: float):
-    """Per-chunk z = H(x·signs)/scale with (min, max) partials.
-
-    x2, signs2: (B, d1·d2) — one row per block-diagonal MAX_D chunk.
-    Returns (z2 (B, d1·d2) f32, mins (B,) f32, maxs (B,) f32).  Sequential
-    lax.map over rows so each row runs the kernel's exact per-chunk dots.
-    """
-    h1 = hadamard_matrix(d1)
-    h2 = hadamard_matrix(d2)
-
-    def one(args):
-        x, s = args
-        xs = ((x * s).astype(jnp.float32)).reshape(d1, d2)
-        t = jax.lax.dot(xs, h2, precision=_HIGHEST)
-        y = jax.lax.dot(h1, t, precision=_HIGHEST)
-        z = y / jnp.float32(scale)
-        return z.reshape(-1), jnp.min(z), jnp.max(z)
-
-    return jax.lax.map(one, (x2, signs2))
 
 
 def binary_plane(z, key, vmin, vmax, dp: int):
@@ -72,7 +29,7 @@ def binary_plane(z, key, vmin, vmax, dp: int):
     """
     delta = vmax - vmin
     p = jnp.where(delta > 0,
-                  (z - vmin) / jnp.where(delta > 0, delta, 1.0), 0.0)
+                  div_rn(z - vmin, jnp.where(delta > 0, delta, 1.0)), 0.0)
     u = tref.uniform(key, dp)
     bits = u < p
     return bp_ref.pack_bits(bits.astype(jnp.uint32), 1)

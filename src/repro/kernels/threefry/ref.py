@@ -1,29 +1,41 @@
-"""Bit-exact jnp reimplementation of JAX's Threefry-2x32 PRNG.
+"""The repo's own Threefry-2x32 PRNG stream (docs/DESIGN.md §10).
 
-The §4.4 seed-trick wire paths draw their supports with
-``jax.random.uniform(key, (d,))`` — the committed golden wire bytes
-(tests/golden/golden_wire.npz) pin those exact draws.  A fused Pallas
-encode/decode kernel therefore cannot use a cheaper in-register hash (the
-way the non-wire kernels use :mod:`repro.kernels.prng`): it must reproduce
-XLA's Threefry stream bit-for-bit or the wire format silently drifts.
+Every draw that decides a wire byte goes through this module: the §4.4
+seed-trick supports, the fixed-k block supports (Gumbel top-k), the
+binary/ternary branch draws and the rotation's Rademacher signs.  Peers
+regenerate each other's supports from seeds, and the committed golden wire
+bytes (tests/golden/golden_wire.npz) pin them, so the stream must not
+depend on anything outside the repo — in particular not on jax's
+partitionable-Threefry switch, whose default flipped to True in jax 0.5
+and changed what ``jax.random.uniform`` returns for the same key.
 
-This module is that stream, written in plain uint32 jnp/lax ops that work
-identically inside Pallas kernel bodies and in XLA — the single source of
-truth the fused wire kernels (repro.kernels.bernoulli_wire,
-repro.kernels.rotated_encode) inline and their oracles call.  Bit-exactness
-against ``jax.random.uniform`` / ``jax.random.bits`` is pinned by
-tests/test_threefry_ref.py across seeds, lengths and parities.
+The stream is defined as jax's *legacy* (non-partitionable) layout, which
+the golden bytes were generated with.  It is written in plain uint32
+jnp/lax ops that work identically inside Pallas kernel bodies and in XLA,
+so the fused wire kernels (repro.kernels.bernoulli_wire,
+repro.kernels.rotated_encode) inline the same functions their oracles
+call.  tests/test_threefry_ref.py pins it against ``jax.random`` with the
+legacy layout switched on inside each test.
 
-Counter layout (must match jax._src.prng.threefry_random_bits): for shape
-(d,) the raw counter ``arange(d)`` is zero-padded to 2·⌈d/2⌉, split in
-half — NOT interleaved — so lane j < half comes from cipher output x0 of
-the pair (j, half + j) and lane j ≥ half from x1 of (j − half, j).  The
-zero pad means the last x1 counter is 0 when d is odd.
+Counter layout (jax's legacy ``threefry_random_bits``): for shape (d,) the
+raw counter ``arange(d)`` is zero-padded to 2·⌈d/2⌉, split in half — NOT
+interleaved — so lane j < half comes from cipher output x0 of the pair
+(j, half + j) and lane j ≥ half from x1 of (j − half, j).  The zero pad
+means the last x1 counter is 0 when d is odd.  A multi-dimensional shape
+draws ``prod(shape)`` lanes and reshapes, row-major.
+
+Keys are either raw (2,) uint32 words (``jax.random.PRNGKey``) or typed
+threefry keys (``jax.random.key``).  ``jax.random.fold_in`` does not
+depend on the layout (it is one cipher call on the key), so callers keep
+deriving keys with it.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Threefry-2x32 constants: key-schedule parity word and the 4-round
 # rotation schedules (20 rounds = 5 groups of 4, alternating schedules).
@@ -74,9 +86,23 @@ def counter_words(idx, d: int):
     return pair, c1, lo
 
 
+def key_words(key):
+    """The (2,) uint32 key words of a raw or typed threefry key."""
+    if jax.dtypes.issubdtype(jnp.result_type(key), jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return jnp.asarray(key).reshape(2).astype(jnp.uint32)
+
+
+def _size(shape) -> int:
+    d = math.prod(shape) if isinstance(shape, tuple) else int(shape)
+    if d >= 1 << 32:  # legacy layout splits the key beyond one counter span
+        raise ValueError(f"draw of {d} lanes exceeds one counter span")
+    return d
+
+
 def random_bits(key, d: int):
-    """Bit-exact ``jax.random.bits(key, (d,), 'uint32')`` for raw (2,) keys."""
-    key = jnp.asarray(key).reshape(2).astype(jnp.uint32)
+    """``jax.random.bits(key, (d,), 'uint32')`` in the legacy layout."""
+    key = key_words(key)
     half = (d + 1) // 2
     cnt = jnp.arange(d, dtype=jnp.uint32)
     cnt = jnp.pad(cnt, (0, 2 * half - d))
@@ -93,9 +119,36 @@ def bits_to_uniform(bits):
         jnp.float32(0.0))
 
 
-def uniform(key, d: int):
-    """Bit-exact ``jax.random.uniform(key, (d,), jnp.float32)``."""
-    return bits_to_uniform(random_bits(key, d))
+def uniform(key, shape):
+    """``jax.random.uniform(key, shape, jnp.float32)``, legacy layout.
+
+    ``shape`` is an int (flat length) or a tuple."""
+    u = bits_to_uniform(random_bits(key, _size(shape)))
+    return u.reshape(shape)
+
+
+def gumbel(key, shape):
+    """``jax.random.gumbel(key, shape, jnp.float32)``, legacy layout.
+
+    jax draws ``uniform(minval=tiny, maxval=1)`` — mantissa-filled floats
+    scaled by the f32 span ``1 − tiny`` (which rounds to 1), shifted by
+    ``tiny`` and clamped below at ``tiny`` — then takes −log(−log(u)).
+    Reproduced op for op.
+    """
+    tiny = jnp.float32(np.finfo(np.float32).tiny)
+    bits = random_bits(key, _size(shape))
+    fbits = (bits >> jnp.uint32(9)) | jnp.uint32(0x3F800000)
+    floats = (jax.lax.bitcast_convert_type(fbits, jnp.float32)
+              - jnp.float32(1.0))
+    u = jnp.maximum(tiny, floats * (jnp.float32(1.0) - tiny) + tiny)
+    return (-jnp.log(-jnp.log(u))).reshape(shape)
+
+
+def rademacher(key, shape, dtype=jnp.float32):
+    """``jax.random.rademacher(key, shape, dtype)``, legacy layout: a
+    Bernoulli(0.5) draw (``uniform < 0.5``) mapped to ±1."""
+    b = (uniform(key, shape) < jnp.float32(0.5)).astype(dtype)
+    return (2 * b - 1).astype(dtype)
 
 
 def uniform_at(key, idx, d: int):
@@ -107,7 +160,7 @@ def uniform_at(key, idx, d: int):
     regenerate just its own slice of every peer's support.  Bit-exact vs
     the full draw (tests/test_threefry_ref.py).
     """
-    key = jnp.asarray(key).reshape(2).astype(jnp.uint32)
+    key = key_words(key)
     c0, c1, lo = counter_words(idx, d)
     o0, o1 = threefry2x32(key[0], key[1], c0, c1)
     return bits_to_uniform(jnp.where(lo, o0, o1))

@@ -44,6 +44,8 @@ def main():
             f"--xla_force_host_platform_device_count={args.devices}")
 
     import jax
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from repro.configs.base import SHAPES, RunConfig, ShapeSpec
     from repro.configs.registry import (get_config, get_run_config,
                                         smoke_config)

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, RunConfig, ShapeSpec
 from repro.models import attention as attn_lib
 from repro.models import encdec as encdec_lib
@@ -137,10 +136,10 @@ def build_serve_fns(mesh, cfg: ArchConfig, run: RunConfig, shape: ShapeSpec,
     del bspec["labels"], bspec["mask"]
 
     vax = "model" if ctx.tp > 1 else None
-    prefill_fn = jax.jit(compat.shard_map(
+    prefill_fn = jax.jit(jax.shard_map(
         sharded_prefill, mesh=mesh, in_specs=(param_ps, bspec),
         out_specs=(cache_ps, P(b, None, vax)), check_vma=False))
-    decode_fn = jax.jit(compat.shard_map(
+    decode_fn = jax.jit(jax.shard_map(
         sharded_decode, mesh=mesh,
         in_specs=(param_ps, cache_ps, tok_ps, P()),
         out_specs=(tok_ps, cache_ps), check_vma=False),

@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import SHAPES, ArchConfig, RunConfig, ShapeSpec
 from repro.core import collectives as coll
 from repro.core import types as core_types
@@ -333,12 +332,12 @@ def build_train_step(mesh, cfg: ArchConfig, run: RunConfig, shape: ShapeSpec,
         ef_ps = jax.tree.map(lambda _: P(), param_ps)
     metrics_ps = {"loss": P(), "grad_norm": P(), "lr": P()}
 
-    step_fn = jax.jit(compat.shard_map(
+    step_fn = jax.jit(jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(param_ps, opt_ps, ef_ps, bspecs, P()),
         out_specs=(param_ps, opt_ps, ef_ps, metrics_ps),
         check_vma=False))
-    init_fn = jax.jit(compat.shard_map(
+    init_fn = jax.jit(jax.shard_map(
         sharded_init, mesh=mesh, in_specs=(P(),),
         out_specs=(param_ps, opt_ps, ef_ps), check_vma=False))
     return step_fn, init_fn, specs, bspecs, plan

@@ -26,7 +26,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import collectives, comm_cost, mse, types  # noqa: E402
 from repro.train import bucketing  # noqa: E402
 
@@ -88,7 +87,7 @@ check("plan.none_all_exact",
       all(b.kind == "exact" for b in plan_none.buckets))
 
 
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
                    out_specs=OUT_SPECS, check_vma=False)
 def sync_once_none(xs, key):
     est, _ = bucketing.sync_grads_bucketed(local_tree(xs), plan_none,
@@ -102,7 +101,7 @@ check("none.exact", err < 1e-5, f"max|err|={err:.2e}")
 
 
 # ---- shared_support: unbiased + per-bucket closed-form MSE ------------------
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
                    out_specs=(OUT_SPECS, P(), P()), check_vma=False)
 def trial_stats(xs, key):
     grads = local_tree(xs)
@@ -153,7 +152,7 @@ cfg_b = mkcfg(encoder=types.EncoderSpec(kind="bernoulli", fraction=0.25,
 plan_b = bucketing.build_plan(SHAPES, SPECS, MESH_AXES, MSIZES, cfg_b)
 
 
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
                    out_specs=OUT_SPECS, check_vma=False)
 def trial_mean_bern(xs, key):
     grads = local_tree(xs)
@@ -186,7 +185,7 @@ check("bern.unbiased", bias_b < 6 * np.sqrt(want_b / D_big),
 # μ); supports never travel (regenerated from fold_in — the §4.4 trick), so
 # measured bits must equal cost_sparse_seed_capacity minus n·r̄_s exactly.
 txt = jax.jit(
-    functools.partial(compat.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
+    functools.partial(jax.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
                       out_specs=OUT_SPECS, check_vma=False)(
         lambda xs, key: bucketing.sync_grads_bucketed(
             local_tree(xs), plan_b, cfg_b, key)[0])
@@ -221,7 +220,7 @@ check("ef.state_keys",
       == {b.bid for b in plan_ef.buckets if b.kind == "compressed"})
 
 
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(IN_SPECS, P()),
                    out_specs=OUT_SPECS, check_vma=False)
 def ef_many(xs, key):
     grads = local_tree(xs)

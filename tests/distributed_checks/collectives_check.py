@@ -21,7 +21,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import collectives, error_feedback, mse, types  # noqa: E402
 from repro.kernels.fixed_k_encode import ops as fk  # noqa: E402
 
@@ -37,7 +36,7 @@ MUS = jnp.mean(XS, axis=-1)
 
 
 def run_mode(cfg: types.CompressionConfig):
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def trial_stats(xs, key):
         x = xs.reshape(D)
@@ -128,7 +127,7 @@ check("bern_wire.mse", abs(float(mse_emp) - want) < 0.12 * want,
       f"emp={float(mse_emp):.4f} want={want:.4f}")
 
 # ---- partial_mean (straggler drop) ------------------------------------------
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
                    check_vma=False)
 def partial(xs):
     x = xs.reshape(D)
@@ -145,7 +144,7 @@ cfg = types.CompressionConfig(
     mode="shared_support", axes=("data",), wire_dtype="float32",
     min_compress_size=0)
 
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                    out_specs=(P(), P("data")), check_vma=False)
 def ef_round(xs, key):
     x = xs.reshape(D)
@@ -160,7 +159,7 @@ est, errs = jax.jit(ef_round)(XS, jax.random.PRNGKey(3))
 check("ef.shapes", errs.shape == (N, D) and bool(jnp.all(jnp.isfinite(errs))))
 # EF over repeated rounds on a *constant* x must drive the aggregate error
 # to zero (compression error is recycled):
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                    out_specs=P(), check_vma=False)
 def ef_many(xs, key):
     x = xs.reshape(D)

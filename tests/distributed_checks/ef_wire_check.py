@@ -33,7 +33,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import registry as cfg_registry  # noqa: E402
 from repro.core import collectives, types, wire  # noqa: E402
 
@@ -79,7 +78,7 @@ def mkcfg(kind, mode, extra, ef):
 def lower_stateful_text(cfg):
     """Lower ONE stateful round with the residual as a real carried input
     (not a constant-folded zero) — what the train step executes."""
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("data"), P("data"), P()),
                        out_specs=(P(), P("data")), check_vma=False)
     def f(xs, state, key):
@@ -92,7 +91,7 @@ def lower_stateful_text(cfg):
 
 
 def lower_plain_text(cfg):
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def f(xs, key):
         return collectives.compressed_mean(xs.reshape(D), key, cfg)
@@ -137,7 +136,7 @@ for name, (kind, mode, extra) in EF_PRESETS.items():
           f"hlo={g_ef[0][1]} codec={codec.wire_bits(N, D, cfg_ef):.0f}")
 
     # ---- contraction: EF time-average beats the EF-free MC average -------- #
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P("data"), P()), out_specs=(P(), P(), P()),
                        check_vma=False)
     def trial(xs, key, cfg_ef=cfg_ef, cfg_plain=cfg_plain):

@@ -13,7 +13,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.distributed.fault_tolerance import (FailurePlan, partial_mean,  # noqa: E402
                                                robust_mean, survivor_index)
 
@@ -23,7 +22,7 @@ XS = jax.random.normal(jax.random.PRNGKey(0), (N, D))
 plan = FailurePlan(rate=0.3, seed=5)
 
 
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"),),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"),),
                    out_specs=P(), check_vma=False)
 def agg(xs):
     return robust_mean(xs.reshape(D), 3, ("data",), plan)
@@ -43,7 +42,7 @@ for rate in (0.0, 0.3, 0.7, 1.0):
     p = FailurePlan(rate=rate, seed=11)
     for step in range(5):
 
-        @functools.partial(compat.shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("data"),), out_specs=P("data"),
                            check_vma=False)
         def view(xs):
@@ -69,7 +68,7 @@ print("[ok] local_alive == alive_mask == drop_mask across steps x rates")
 p = FailurePlan(rate=0.5, seed=23)
 for step in range(8):
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"),),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"),),
                        out_specs=P(), check_vma=False)
     def agg_s(xs, _step=step):
         return robust_mean(xs.reshape(D), _step, ("data",), p)
@@ -83,7 +82,7 @@ print("[ok] robust_mean == live-subset mean across an 8-step grid")
 
 # all-dead partial_mean is NaN by contract (0/0): an impossible state under
 # FailurePlan's survivor rule must poison the step, not silently zero it.
-@functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"),),
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"),),
                    out_specs=P(), check_vma=False)
 def all_dead(xs):
     return partial_mean(xs.reshape(D), jnp.float32(0.0), ("data",))

@@ -38,7 +38,6 @@ import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import registry as cfg_registry  # noqa: E402
 from repro.core import collectives, comm_cost, types, wire  # noqa: E402
 from repro.train import bucketing  # noqa: E402
@@ -89,7 +88,7 @@ def mesh_for(n):
 
 
 def run_mean(cfg, n, xs, key):
-    @functools.partial(compat.shard_map, mesh=mesh_for(n),
+    @functools.partial(jax.shard_map, mesh=mesh_for(n),
                        in_specs=(P("data"), P()), out_specs=P(),
                        check_vma=False)
     def f(x, k):
@@ -174,10 +173,10 @@ key0 = jax.random.PRNGKey(1)
 GXS = {nm: jax.random.normal(jax.random.fold_in(key0, h), (N,) + SHAPES[nm])
        for h, nm in enumerate(sorted(SHAPES))}
 txt = jax.jit(
-    functools.partial(compat.shard_map, mesh=mesh_for(N),
+    functools.partial(jax.shard_map, mesh=mesh_for(N),
                       in_specs=({nm: P("data", None) for nm in SHAPES}, P()),
                       out_specs={nm: P() for nm in SHAPES},
-                      check_vma=False, check_rep=False)(
+                      check_vma=False)(
         lambda xs, key: bucketing.sync_grads_bucketed(
             {nm: xs[nm].reshape(SHAPES[nm]) for nm in xs},
             plan, BCFG, key)[0])
@@ -197,11 +196,11 @@ fplan = bucketing.build_plan(SHAPES, SPECS, ("data",), {"data": N}, FCFG)
 
 
 def sync(plan_, cfg_):
-    @functools.partial(compat.shard_map, mesh=mesh_for(N),
+    @functools.partial(jax.shard_map, mesh=mesh_for(N),
                        in_specs=({nm: P("data", None) for nm in SHAPES},
                                  P()),
                        out_specs={nm: P() for nm in SHAPES},
-                       check_vma=False, check_rep=False)
+                       check_vma=False)
     def f(xs, key):
         return bucketing.sync_grads_bucketed(
             {nm: xs[nm].reshape(SHAPES[nm]) for nm in xs},

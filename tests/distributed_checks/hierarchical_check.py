@@ -35,7 +35,6 @@ import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import collectives, comm_cost, types, wire  # noqa: E402
 from repro.train import bucketing  # noqa: E402
 
@@ -81,9 +80,9 @@ def mesh_for(n):
 
 
 def run_hier(cfg, mesh, xs, key):
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(("pod", "data")), P()), out_specs=P(),
-                       check_vma=False, check_rep=False)
+                       check_vma=False)
     def f(xs, key):
         return collectives.compressed_mean(xs.reshape(D), key, cfg)
     return jax.jit(f)
@@ -93,9 +92,9 @@ def run_ref(cfg, mesh):
     """pmean over the inner axis, then the FLAT codec across pod."""
     flat = dataclasses.replace(cfg, inner_axes=(), scatter_decode=False)
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(P(("pod", "data")), P()), out_specs=P(),
-                       check_vma=False, check_rep=False)
+                       check_vma=False)
     def f(xs, key):
         v = jax.lax.pmean(xs.reshape(D), ("data",))
         return collectives.compressed_mean(v, key, flat)
@@ -195,11 +194,11 @@ key0 = jax.random.PRNGKey(1)
 GXS = {nm: jax.random.normal(jax.random.fold_in(key0, h), (N,) + SHAPES[nm])
        for h, nm in enumerate(sorted(SHAPES))}
 txt = jax.jit(
-    functools.partial(compat.shard_map, mesh=mesh,
+    functools.partial(jax.shard_map, mesh=mesh,
                       in_specs=({nm: P(("pod", "data"), None)
                                  for nm in SHAPES}, P()),
                       out_specs={nm: P() for nm in SHAPES},
-                      check_vma=False, check_rep=False)(
+                      check_vma=False)(
         lambda xs, key: bucketing.sync_grads_bucketed(
             {nm: xs[nm].reshape(SHAPES[nm]) for nm in xs},
             plan, BCFG, key)[0])

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.registry import compression_preset
 from repro.core import types
 from repro.train import bucketing
@@ -81,7 +80,7 @@ def make_sync_steps(mesh, n_layers: int, cfg, plan):
     efspec = {b.bid: P() for b in plan.buckets
               if use_ef and b.kind == "compressed"}
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(pspec, efspec, P("data"), P()),
                        out_specs=(pspec, efspec), check_vma=False)
     def post(params, ef, x, key):
@@ -90,7 +89,7 @@ def make_sync_steps(mesh, n_layers: int, cfg, plan):
             grads, plan, cfg, key, ef if use_ef else None)
         return g, (new_ef if use_ef else {})
 
-    @functools.partial(compat.shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(pspec, efspec, P("data"), P()),
                        out_specs=(pspec, efspec), check_vma=False)
     def ovl(params, ef, x, key):

@@ -29,7 +29,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import bitplane, collectives, comm_cost, types  # noqa: E402
 from repro.train import bucketing  # noqa: E402
 
@@ -58,7 +57,7 @@ def mkcfg(kind, mode, frac=0.125):
 
 
 def run_mean(cfg):
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def f(xs, key):
         return collectives.compressed_mean(xs.reshape(D), key, cfg)
@@ -99,7 +98,7 @@ for kind in ("binary", "ternary"):
           f"compressed={n_cmp} exact={n_ex}")
 
     txt = jax.jit(
-        functools.partial(compat.shard_map, mesh=mesh,
+        functools.partial(jax.shard_map, mesh=mesh,
                           in_specs=(IN_SPECS, P()), out_specs=OUT_SPECS,
                           check_vma=False)(
             lambda xs, key, plan=plan, cfg=cfg: bucketing.sync_grads_bucketed(

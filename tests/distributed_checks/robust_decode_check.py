@@ -59,7 +59,6 @@ import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import registry as cfg_registry  # noqa: E402
 from repro.core import collectives, mse, types, wire  # noqa: E402
 from repro.core.wire import base as wire_base  # noqa: E402
@@ -97,7 +96,7 @@ def adversarial_round(cfg):
     all traced operands — one cache entry serves the whole matrix."""
     codec = wire.resolve(cfg)
 
-    @functools.partial(compat.shard_map, mesh=MESH,
+    @functools.partial(jax.shard_map, mesh=MESH,
                        in_specs=(P("data"), P(), P(), P(), P()),
                        out_specs=P(), check_vma=False)
     def f(x, key, adv, mode_idx, mask):
@@ -110,7 +109,7 @@ def adversarial_round(cfg):
 
 
 def masked_mean_round(cfg):
-    @functools.partial(compat.shard_map, mesh=MESH,
+    @functools.partial(jax.shard_map, mesh=MESH,
                        in_specs=(P("data"), P(), P()), out_specs=P(),
                        check_vma=False)
     def f(x, key, mask):
@@ -212,7 +211,7 @@ for nm in GATHER_PRESETS:
     # the FULL mask equals the unmasked production round in value (the
     # unmasked path lowers the FUSED decode, the masked path the stacked
     # reduction — different programs, same mean).
-    @functools.partial(compat.shard_map, mesh=MESH,
+    @functools.partial(jax.shard_map, mesh=MESH,
                        in_specs=(P("data"), P()), out_specs=P(),
                        check_vma=False)
     def plain_f(x, k):
@@ -301,7 +300,7 @@ plan = ft.FailurePlan(rate=0.5, seed=4)
 cfg_b = preset("bernoulli_seed_1bit", "trim(1)")
 
 
-@functools.partial(compat.shard_map, mesh=MESH,
+@functools.partial(jax.shard_map, mesh=MESH,
                    in_specs=(P("data"), P()), out_specs=P(),
                    check_vma=False)
 def plan_round(x, key):

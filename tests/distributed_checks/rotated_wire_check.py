@@ -31,7 +31,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import registry as cfg_registry  # noqa: E402
 from repro.core import collectives, comm_cost, mse, rotation, types, wire  # noqa: E402
 
@@ -62,7 +61,7 @@ def preset(name):
 
 
 def lower_text(cfg):
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def f(xs, key):
         return collectives.compressed_mean(xs.reshape(D), key, cfg)
@@ -109,7 +108,7 @@ for name in ("rotated_binary", "rotated_fixed_k"):
           f"cost={cost:.0f} wire={wb_rot:.0f} seed={seed:.0f}")
 
     # ---- Monte-Carlo wire MSE == §7.2 closed form ------------------------- #
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=(P(), P()), check_vma=False)
     def trial_stats(xs, key, cfg=cfg_rot):
         x = xs.reshape(D)

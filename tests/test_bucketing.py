@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.base import RunConfig
 from repro.configs.registry import list_archs, smoke_config
 from repro.core import types as core_types
@@ -156,7 +155,7 @@ def test_bucketed_sync_identity_on_one_device():
     from jax.sharding import PartitionSpec as P
     pspecs = {n: P() for n in shapes}
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(pspecs, P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(pspecs, P()),
                        out_specs=pspecs, check_vma=False)
     def sync(g, key):
         est, _ = bucketing.sync_grads_bucketed(g, plan, cmp, key)

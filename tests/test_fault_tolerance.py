@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed.fault_tolerance import (FailurePlan, partial_mean,
                                                survivor_index)
 
@@ -94,7 +93,7 @@ def test_failure_plan_views_share_one_draw():
     mesh = jax.make_mesh((1,), ("data",))
     for step in range(6):
 
-        @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P(),),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(),),
                            out_specs=P(), check_vma=False)
         def local(x):
             del x
@@ -107,7 +106,7 @@ def test_failure_plan_views_share_one_draw():
 def _pmean_1dev(x, alive):
     mesh = jax.make_mesh((1,), ("data",))
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P(None), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(None), P()),
                        out_specs=P(), check_vma=False)
     def f(x, alive):
         return partial_mean(x * alive, alive, ("data",))

@@ -159,8 +159,7 @@ def test_bernoulli_support_shards_stitch_to_full_draw():
     d, n, p = 1000, 3, 1.0 / 16
     keys = jnp.stack([jax.random.fold_in(jax.random.PRNGKey(5), i)
                       for i in range(n)])
-    full = np.asarray(jax.vmap(
-        lambda k: jax.random.uniform(k, (d,), dtype=jnp.float32))(keys) < p)
+    full = np.asarray(jax.vmap(lambda k: tf_ref.uniform(k, d))(keys) < p)
     for nshards in (2, 3):
         ds = -(-d // nshards)
         parts = [bw_ref.support_shard(keys, p, d, s * ds, ds)
@@ -198,7 +197,7 @@ def test_uniform_at_matches_batch_uniform():
     # must be bit-exact vs the batch draw peers encode with
     key = jax.random.PRNGKey(7)
     for d in (1, 2, 255, 256, 257, 1000):
-        want = np.asarray(jax.random.uniform(key, (d,), dtype=jnp.float32))
+        want = np.asarray(tf_ref.uniform(key, d))
         got = np.asarray(tf_ref.uniform_at(
             key, jnp.arange(d, dtype=jnp.int32), d))
         assert np.array_equal(got, want), d

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import types as core_types
 from repro.train import bucketing
 
@@ -65,7 +64,7 @@ def test_overlap_identity_on_one_device():
     def loss(p):
         return jnp.sum(p["a"]) + jnp.sum(jnp.sin(p["b"]))
 
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(pspec, P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(pspec, P()),
                        out_specs=(pspec, pspec), check_vma=False)
     def grads_both(p, key):
         g_ref = jax.grad(loss)(p)

@@ -1,58 +1,57 @@
-"""Oracle-equivalence for the fused §7.2 rotate+encode kernel pair
-(repro.kernels.rotated_encode) and consistency of the fused dispatch with
-the CPU production chain.
+"""Oracle-equivalence for the fused §7.2 rotate+encode kernel pair and
+consistency of the fused dispatch with the CPU production chain.
 
-Kernel ↔ oracle is EXACT (interpret mode): the oracle deliberately uses the
-same Kronecker-factorized FWHT as the TPU hadamard kernel.  Fused ↔ CPU
-production (butterfly FWHT) agrees on every plane bit and allclose on the
-(vmin, vmax) tail — the two FWHT formulations differ by f32 rounding, which
-moves the bracket scalars by an ulp but (empirically and by the ~2⁻²⁴
-threshold-crossing probability) not the stochastic bits."""
+The rotation kernel runs the butterfly FWHT of kernels/hadamard/ref.py
+with the same f32 add chain, and the threshold uses the same correctly
+rounded quotient (repro.kernels.numerics), so kernel ↔ production is EXACT
+— every plane bit and the (vmin, vmax) tail (interpret mode, CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import bitplane, rotation
+from repro.kernels.hadamard import hadamard as hk
 from repro.kernels.hadamard import ops as hops
 from repro.kernels.rotated_encode import kernel, ops, ref
 
 
 def _setup(seed, dp):
     c = min(dp, hops.MAX_D)
-    d1, d2 = hops._factorize(c)
-    scale = float(np.sqrt(np.float32(c)))
     key = jax.random.PRNGKey(seed)
     krot = rotation.rotation_key(key)
     x = jax.random.normal(jax.random.PRNGKey(seed + 50), (dp,), jnp.float32)
     signs = rotation.rademacher_diag(krot, dp, jnp.float32)
-    return key, x.reshape(-1, c), signs.reshape(-1, c), d1, d2, scale
+    return key, krot, x, x.reshape(-1, c), signs.reshape(-1, c), c
 
 
 @pytest.mark.parametrize("seed", (0, 5))
-@pytest.mark.parametrize("dp", (256, 1024, 4096))
+@pytest.mark.parametrize("dp", (1024, 4096, 1 << 16))
 def test_rotate_minmax_kernel_exact(seed, dp):
-    key, x2, s2, d1, d2, scale = _setup(seed, dp)
-    z_r, mn_r, mx_r = ref.rotate_minmax(x2, s2, d1=d1, d2=d2, scale=scale)
-    z_k, mm = kernel.rotate_minmax_pallas(x2, s2, d1=d1, d2=d2, scale=scale,
-                                          interpret=True)
-    np.testing.assert_array_equal(np.asarray(z_k), np.asarray(z_r))
-    np.testing.assert_array_equal(np.asarray(mm[:, 0]), np.asarray(mn_r))
-    np.testing.assert_array_equal(np.asarray(mm[:, 1]), np.asarray(mx_r))
+    """Fused signs + butterfly + scale + (min, max) == rotation.rotate."""
+    key, krot, x, x2, s2, c = _setup(seed, dp)
+    want = np.asarray(rotation.rotate(krot, x)).reshape(-1, c)
+    z_k, mm = hk.fwht_pallas(x2, s2, inv_scale=rotation.inv_scale(c),
+                             with_minmax=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(z_k), want)
+    np.testing.assert_array_equal(np.asarray(mm[:, 0]), want.min(axis=1))
+    np.testing.assert_array_equal(np.asarray(mm[:, 1]), want.max(axis=1))
 
 
 @pytest.mark.parametrize("seed", (0, 5))
 @pytest.mark.parametrize("dp", (256, 1024, 4096))
 def test_encode_pack_kernel_exact(seed, dp):
-    key, x2, s2, d1, d2, scale = _setup(seed, dp)
-    z, mn, mx = ref.rotate_minmax(x2, s2, d1=d1, d2=d2, scale=scale)
-    z = z.reshape(-1)
-    vmin, vmax = jnp.min(mn), jnp.max(mx)
+    key, krot, x, _, _, _ = _setup(seed, dp)
+    z = rotation.rotate(krot, x)
+    vmin, vmax = jnp.min(z), jnp.max(z)
     kenc = jax.random.fold_in(key, 2)
     want = ref.binary_plane(z, kenc, vmin, vmax, dp)
     got = kernel.encode_pack_pallas(z, kenc, vmin, vmax, dp=dp,
                                     interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the oracle is the production encoder's plane
+    prod = bitplane.binary_pack(z, kenc, "float32")[:-2]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(prod))
 
 
 def test_encode_pack_degenerate_delta_zero():
@@ -75,19 +74,13 @@ def test_fused_pack_binary_consistent_with_production(seed, d, wire_dtype):
     prod = ops.pack_binary(x, key, 1, wire_dtype)
     fused = ops.pack_binary(x, key, 1, wire_dtype, force_pallas=True)
     assert prod.shape == fused.shape and prod.dtype == fused.dtype
-    dp = rotation.padded_dim(d)
-    nplane = -(-dp // 32)
-    # every stochastic plane bit identical; only the tail scalars may move
-    np.testing.assert_array_equal(np.asarray(fused[:nplane]),
-                                  np.asarray(prod[:nplane]))
-    r1 = np.asarray(bitplane.binary_unpack(prod, dp, wire_dtype))
-    r2 = np.asarray(bitplane.binary_unpack(fused, dp, wire_dtype))
-    np.testing.assert_allclose(r2, r1, rtol=1e-5, atol=1e-6)
+    # every plane bit and both tail scalars identical
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(prod))
 
 
 def test_small_dp_uses_production_chain_verbatim():
-    """dp < 256 (degenerate MXU tiles) must fall back to the exact CPU
-    chain even under force_pallas."""
+    """dp below one (8, 128) tile must fall back to the exact CPU chain
+    even under force_pallas."""
     d = 100
     key = jax.random.PRNGKey(1)
     x = jax.random.normal(jax.random.PRNGKey(2), (d,), jnp.float32)

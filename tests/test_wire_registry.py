@@ -322,7 +322,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, functools, json, re
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.core import collectives, types
 
 N, D = 8, 5000
@@ -334,7 +333,7 @@ for name, kw in CFGS.items():
         encoder=types.EncoderSpec(**kw["encoder"]), mode="gather_decode",
         axes=("data",), wire_dtype=kw["wire_dtype"], min_compress_size=0,
         error_feedback=kw["error_feedback"])
-    @functools.partial(compat.shard_map, mesh=mesh, in_specs=(P("data"), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P()),
                        out_specs=P(), check_vma=False)
     def f(xs, key):
         return collectives.compressed_mean(xs.reshape(D), key, cfg)

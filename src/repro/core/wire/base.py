@@ -44,6 +44,17 @@ registered codec):  ``comm_cost_bits == wire_bits + seed_bits`` — the
 analytic §4 cost splits into bits that physically travel (the gathered
 buffer, HLO-measurable) plus bits that ride the implicit PRNG (the §4.4
 seed trick: supports/rotations regenerate peer-side from the shared key).
+
+Device scopes (``jax.named_scope``, metadata only: they reach each compiled
+instruction's ``op_name`` and change no instruction).  Every operation of
+one round sits under a stage: ``encode`` (pack, the EF twin), ``exchange``
+(the wire all_gather, the psum codecs' pmean, the decoded-shard
+all_gather, the inner-axes pre-reduce), ``decode`` (with ``trim`` around
+the robust reductions), and from the wrappers ``rotate`` / ``unrotate``
+(:class:`~repro.core.wire.rotated.RotatedCodec`, the EF twin's rotation)
+and ``ef`` (the residual add and update).  The hooks below set them, not
+the codecs; where stages nest (the EF twin's rotation under ``encode``)
+the innermost names the operation.
 """
 from __future__ import annotations
 
@@ -309,7 +320,8 @@ class WireCodec:
         if kind == "mean" and drop_mask is None:
             return self.decode_gathered(rows, key, cfg, d, n)
         stack = self.decode_rows(rows, key, cfg, d, n)
-        return robust.reduce_rows(stack, kind, f, drop_mask)
+        with jax.named_scope("trim"):
+            return robust.reduce_rows(stack, kind, f, drop_mask)
 
     def decode_gathered_shard(self, rows, key, cfg: t.CompressionConfig,
                               d: int, n: int, shard, nshards: int):
@@ -361,7 +373,8 @@ class WireCodec:
         ``drop_mask`` as in :meth:`mean_flat`.
         """
         if cfg.inner_axes:
-            flat = jax.lax.pmean(flat, cfg.inner_axes)
+            with jax.named_scope("exchange"):
+                flat = jax.lax.pmean(flat, cfg.inner_axes)
         return self._round_stateful(flat, state, key, cfg, drop_mask)
 
     # ---- the collective --------------------------------------------------- #
@@ -386,7 +399,8 @@ class WireCodec:
         inner (intra-host) pre-reduce is assumed healthy.
         """
         if cfg.inner_axes:
-            flat = jax.lax.pmean(flat, cfg.inner_axes)
+            with jax.named_scope("exchange"):
+                flat = jax.lax.pmean(flat, cfg.inner_axes)
         return self._round(flat, key, cfg, drop_mask)
 
     def _round(self, flat, key, cfg: t.CompressionConfig, drop_mask=None):
@@ -403,17 +417,21 @@ class WireCodec:
         outermost layer.
         """
         d = flat.shape[0]
-        rank, n = axis_rank_size(cfg.axes)
-        buf = self.pack(flat, key, rank, cfg)
+        with jax.named_scope("encode"):
+            rank, n = axis_rank_size(cfg.axes)
+            buf = self.pack(flat, key, rank, cfg)
         if self.reduce == "psum":
-            if drop_mask is None:
-                wire = jax.lax.pmean(buf, cfg.axes)
-            else:
-                keep = drop_mask[rank].astype(jnp.float32)
-                num = jax.lax.psum(buf.astype(jnp.float32) * keep, cfg.axes)
-                den = jax.lax.psum(keep, cfg.axes)
-                wire = (num / den).astype(buf.dtype)
-            return self.decode_reduced(wire, key, cfg, d)
+            with jax.named_scope("exchange"):
+                if drop_mask is None:
+                    wire = jax.lax.pmean(buf, cfg.axes)
+                else:
+                    keep = drop_mask[rank].astype(jnp.float32)
+                    num = jax.lax.psum(buf.astype(jnp.float32) * keep,
+                                       cfg.axes)
+                    den = jax.lax.psum(keep, cfg.axes)
+                    wire = (num / den).astype(buf.dtype)
+            with jax.named_scope("decode"):
+                return self.decode_reduced(wire, key, cfg, d)
         return self.gather_decode(buf, key, cfg, d, n, drop_mask)
 
     def _round_stateful(self, flat, state, key, cfg: t.CompressionConfig,
@@ -445,22 +463,28 @@ class WireCodec:
         decode, so the §12/§13 word-aligned shard windows survive and the
         composed result equals the flat robust decode bit-for-bit.
         """
-        rows = gather_nested(buf, cfg.axes).reshape(n, buf.shape[0])
+        with jax.named_scope("exchange"):
+            rows = gather_nested(buf, cfg.axes).reshape(n, buf.shape[0])
         kind, f = robust.parse_policy(cfg.decode_policy)
         if cfg.scatter_decode:
             saxes = scatter_axes(cfg)
-            shard, nshards = axis_rank_size(saxes)
-            if kind == "mean" and drop_mask is None:
-                part = self.decode_gathered_shard(rows, key, cfg, d, n,
-                                                  shard, nshards)
-            else:
-                ds = scatter_shard_len(d, nshards, self.scatter_align(cfg))
-                stack = self.decode_rows_shard(rows, key, cfg, d, n,
-                                               shard * ds, ds, nshards)
-                part = robust.reduce_rows(stack, kind, f, drop_mask)
-            full = gather_nested(part, saxes).reshape(-1)
-            return full[:d]
-        return self.decode_rows_reduce(rows, key, cfg, d, n, drop_mask)
+            with jax.named_scope("decode"):
+                shard, nshards = axis_rank_size(saxes)
+                if kind == "mean" and drop_mask is None:
+                    part = self.decode_gathered_shard(rows, key, cfg, d, n,
+                                                      shard, nshards)
+                else:
+                    ds = scatter_shard_len(d, nshards,
+                                           self.scatter_align(cfg))
+                    stack = self.decode_rows_shard(rows, key, cfg, d, n,
+                                                   shard * ds, ds, nshards)
+                    with jax.named_scope("trim"):
+                        part = robust.reduce_rows(stack, kind, f, drop_mask)
+            with jax.named_scope("exchange"):
+                full = gather_nested(part, saxes).reshape(-1)
+                return full[:d]
+        with jax.named_scope("decode"):
+            return self.decode_rows_reduce(rows, key, cfg, d, n, drop_mask)
 
     def mean(self, x, key, cfg: t.CompressionConfig, drop_mask=None):
         """Shape/dtype-preserving wrapper around :meth:`mean_flat`."""

@@ -242,10 +242,12 @@ def _twin_pack_recon(codec, flat, key, rank, cfg):
         buf = hook(flat, key, rank, cfg)
         return buf, codec.unpack(buf, rank, key, cfg, flat.shape[0])
     if isinstance(codec, rotated.RotatedCodec):
-        krot = rotation.rotation_key(key)
-        z = rotation.rotate(krot, flat)
+        with jax.named_scope("rotate"):
+            krot = rotation.rotation_key(key)
+            z = rotation.rotate(krot, flat)
         buf, rz = _twin_pack_recon(codec.inner, z, key, rank, cfg)
-        return buf, rotation.unrotate(krot, rz, flat.shape[0])
+        with jax.named_scope("unrotate"):
+            return buf, rotation.unrotate(krot, rz, flat.shape[0])
     if isinstance(codec, codecs.TernaryCodec):  # incl. TernaryOptCodec
         return _ternary_twin(flat, codec._cap(flat.shape[0], cfg), cfg)
     if isinstance(codec, codecs.BinaryCodec):
@@ -406,23 +408,30 @@ class EFCodec(base.WireCodec):
         cross-host message — the only lossy step.
         """
         d = flat.shape[0]
-        rank, n = base.axis_rank_size(cfg.axes)
-        v = flat + state
-        buf, recon = _twin_pack_recon(self.inner, v, key, rank, cfg)
+        with jax.named_scope("encode"):
+            rank, n = base.axis_rank_size(cfg.axes)
+        with jax.named_scope("ef"):
+            v = flat + state
+        with jax.named_scope("encode"):
+            buf, recon = _twin_pack_recon(self.inner, v, key, rank, cfg)
         if self.reduce == "psum":
-            if drop_mask is None:
-                wire = jax.lax.pmean(buf, cfg.axes)
-            else:
-                # masked weighted psum, mirroring base._round: dropped
-                # peers contribute zero to both numerator and count.
-                keep = drop_mask[rank].astype(jnp.float32)
-                num = jax.lax.psum(buf.astype(jnp.float32) * keep, cfg.axes)
-                den = jax.lax.psum(keep, cfg.axes)
-                wire = (num / den).astype(buf.dtype)
-            est = self.inner.decode_reduced(wire, key, cfg, d)
+            with jax.named_scope("exchange"):
+                if drop_mask is None:
+                    wire = jax.lax.pmean(buf, cfg.axes)
+                else:
+                    # masked weighted psum, mirroring base._round: dropped
+                    # peers contribute zero to both numerator and count.
+                    keep = drop_mask[rank].astype(jnp.float32)
+                    num = jax.lax.psum(buf.astype(jnp.float32) * keep,
+                                       cfg.axes)
+                    den = jax.lax.psum(keep, cfg.axes)
+                    wire = (num / den).astype(buf.dtype)
+            with jax.named_scope("decode"):
+                est = self.inner.decode_reduced(wire, key, cfg, d)
         else:
             est = self.gather_decode(buf, key, cfg, d, n, drop_mask)
-        return est, v - recon
+        with jax.named_scope("ef"):
+            return est, v - recon
 
     def _round(self, flat, key, cfg, drop_mask=None):
         """Stateless round: zero residual, state discarded.

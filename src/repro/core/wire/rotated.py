@@ -30,6 +30,8 @@ Reduce kind is inherited: the rotation composes with gather codecs
 """
 from __future__ import annotations
 
+import jax
+
 from repro.core import rotation
 from repro.core import types as t
 from repro.core.wire import base
@@ -90,19 +92,20 @@ class RotatedCodec(base.WireCodec):
             # dispatcher falls back to exactly the chain below off-TPU
             # (repro.kernels.rotated_encode).
             return ro_ops.pack_binary(flat, key, rank, cfg.wire_dtype)
-        z = rotation.rotate(rotation.rotation_key(key), flat)
+        with jax.named_scope("rotate"):
+            z = rotation.rotate(rotation.rotation_key(key), flat)
         return self.inner.pack(z, key, rank, cfg)
 
     def unpack(self, row, peer, key, cfg, d):
         dp = rotation.padded_dim(d)
         z = self.inner.unpack(row, peer, key, cfg, dp)
-        return rotation.unrotate(rotation.rotation_key(key), z, d)
+        return _unrotate(key, z, d)
 
     def decode_gathered(self, rows, key, cfg, d, n):
         # unrotate once, after the averaging decode (linearity of Q).
         dp = rotation.padded_dim(d)
         zbar = self.inner.decode_gathered(rows, key, cfg, dp, n)
-        return rotation.unrotate(rotation.rotation_key(key), zbar, d)
+        return _unrotate(key, zbar, d)
 
     def scatter_align(self, cfg):
         return self.inner.scatter_align(cfg)
@@ -122,7 +125,7 @@ class RotatedCodec(base.WireCodec):
         # the single inverse rotation maps the robust estimate back.
         dp = rotation.padded_dim(d)
         zbar = self.inner.gather_decode(buf, key, cfg, dp, n, drop_mask)
-        return rotation.unrotate(rotation.rotation_key(key), zbar, d)
+        return _unrotate(key, zbar, d)
 
     def decode_rows_reduce(self, rows, key, cfg, d, n, drop_mask=None):
         # collective-free policy decode: the reduction runs in rotated
@@ -130,12 +133,12 @@ class RotatedCodec(base.WireCodec):
         dp = rotation.padded_dim(d)
         zbar = self.inner.decode_rows_reduce(rows, key, cfg, dp, n,
                                              drop_mask)
-        return rotation.unrotate(rotation.rotation_key(key), zbar, d)
+        return _unrotate(key, zbar, d)
 
     def decode_reduced(self, wire, key, cfg, d):
         dp = rotation.padded_dim(d)
         zbar = self.inner.decode_reduced(wire, key, cfg, dp)
-        return rotation.unrotate(rotation.rotation_key(key), zbar, d)
+        return _unrotate(key, zbar, d)
 
     # ---- codec state: forwarded in the rotated basis ---------------------- #
 
@@ -152,15 +155,26 @@ class RotatedCodec(base.WireCodec):
         # scatter-decode decomposition, when on, shards the ROTATED
         # estimate and reassembles all dp coordinates before unrotating.
         d = flat.shape[0]
-        krot = rotation.rotation_key(key)
-        z = rotation.rotate(krot, flat)
+        with jax.named_scope("rotate"):
+            krot = rotation.rotation_key(key)
+            z = rotation.rotate(krot, flat)
         zbar, new_state = self.inner._round_stateful(z, state, key, cfg,
                                                      drop_mask)
-        return rotation.unrotate(krot, zbar, d), new_state
+        with jax.named_scope("unrotate"):
+            return rotation.unrotate(krot, zbar, d), new_state
 
     def _round(self, flat, key, cfg, drop_mask=None):
         d = flat.shape[0]
-        krot = rotation.rotation_key(key)
-        z = rotation.rotate(krot, flat)
+        with jax.named_scope("rotate"):
+            krot = rotation.rotation_key(key)
+            z = rotation.rotate(krot, flat)
         zbar = self.inner._round(z, key, cfg, drop_mask)
-        return rotation.unrotate(krot, zbar, d)
+        with jax.named_scope("unrotate"):
+            return rotation.unrotate(krot, zbar, d)
+
+
+def _unrotate(key, z, d: int):
+    """The inverse rotation of an estimate, under the ``unrotate`` scope
+    (the rotation key's derivation included)."""
+    with jax.named_scope("unrotate"):
+        return rotation.unrotate(rotation.rotation_key(key), z, d)

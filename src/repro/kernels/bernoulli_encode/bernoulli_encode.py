@@ -59,5 +59,6 @@ def bernoulli_encode_2d(x, scal, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((BM, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
+        name="bernoulli_encode",
         interpret=interpret,
     )(x, scal)

@@ -225,6 +225,7 @@ def encode_pallas(flat, key, mu, *, p: float, cap: int,
         functools.partial(_encode_kernel, d=d, cap=cap, scaled=scaled),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_cap, LANES), jnp.float32),
+        name="bernoulli_wire_encode",
         interpret=interpret,
     )(key, params, x2)
     return out.reshape(-1)[:cap]
@@ -288,6 +289,7 @@ def decode_sum_pallas(bufs, mus, keys, *, p: float, cap: int, d: int,
         functools.partial(_decode_kernel, d=d, cap=cap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_d, LANES), jnp.float32),
+        name="bernoulli_wire_decode_sum",
         interpret=interpret,
     )(keys, mus, params, bufs3)
     return out.reshape(-1)[:d]
@@ -375,6 +377,7 @@ def decode_sum_shard_pallas(bufs, mus, keys, prior, start, *, p: float,
         functools.partial(_decode_shard_kernel, d=d, cap=cap, ds=ds),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_ds, LANES), jnp.float32),
+        name="bernoulli_wire_decode_sum_shard",
         interpret=interpret,
     )(keys, mus, params, prior, off, bufs3)
     return out.reshape(-1)[:ds]

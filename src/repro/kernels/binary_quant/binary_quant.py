@@ -60,5 +60,6 @@ def binary_encode_2d(x, scal, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((BM, LANES // 8), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c // 8), jnp.uint8),
+        name="binary_quant_encode",
         interpret=interpret,
     )(x, scal)

@@ -103,6 +103,7 @@ def pack_bits_2d(vals, width: int, *, interpret: bool = False):
         in_specs=[pl.BlockSpec((BM_PACK, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((out_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r * width // 32, LANES), jnp.uint32),
+        name="bitplane_pack",
         interpret=interpret,
     )(vals)
 
@@ -143,6 +144,7 @@ def binary_accum_2d(words, centers, *, interpret: bool = False):
                   pl.BlockSpec((n, LANES), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((BM_ACCUM, out_lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, out_lanes), jnp.float32),
+        name="binary_accum",
         interpret=interpret,
     )(words, centers)
 
@@ -159,5 +161,6 @@ def unpack_bits_2d(words, width: int, *, interpret: bool = False):
         in_specs=[pl.BlockSpec((BM_UNPACK, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BM_UNPACK, out_lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, out_lanes), jnp.uint32),
+        name="bitplane_unpack",
         interpret=interpret,
     )(words)

@@ -48,5 +48,6 @@ def fixed_k_gather_2d(x, block_ids, scal, *, interpret: bool = False):
             out_specs=pl.BlockSpec((1, ROWS, BS), lambda i, ids: (i, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((kb, ROWS, BS), x.dtype),
+        name="fixed_k_encode",
         interpret=interpret,
     )(block_ids, x, scal)

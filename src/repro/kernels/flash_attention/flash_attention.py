@@ -140,6 +140,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q4, k4, v4)
     return out.reshape(b, hq, sq, hd), lse.reshape(b, hq, sq)
@@ -302,6 +303,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=None,
             pltpu.VMEM((bk, hd), jnp.float32),
             pltpu.VMEM((bk, hd), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q4, k4, v4, do4, lse2, delta2)
 
@@ -331,6 +333,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=None,
         out_specs=pl.BlockSpec((1, bq, hd), q_map),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q4, k4, v4, do4, lse2, delta2)
 

@@ -139,6 +139,7 @@ def fwht_pallas(x, signs=None, *, inv_scale=None, with_minmax: bool = False,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="hadamard",
         interpret=interpret,
     )(*ins)
     y, mm = outs[0], (outs[1] if mm_tile else None)
@@ -161,6 +162,7 @@ def fwht_pallas(x, signs=None, *, inv_scale=None, with_minmax: bool = False,
             in_specs=[spec],
             out_specs=out_specs,
             out_shape=out_shape,
+            name="hadamard_cross",
             interpret=interpret,
         )(y4)
         y = outs[0]

@@ -81,6 +81,7 @@ def encode_pack_pallas(z, key, vmin, vmax, *, dp: int,
         functools.partial(_encode_pack_kernel, dp=dp),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows // 32, LANES), jnp.uint32),
+        name="rotated_encode",
         interpret=interpret,
     )(key, params, z2)
     return words.reshape(-1)[:-(-dp // 32)]

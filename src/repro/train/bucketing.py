@@ -384,20 +384,33 @@ def _bucket_round(grads: Mapping[str, jax.Array], b: Bucket, j: int,
     scatter_decode, all_gathers decoded shards over them); only the
     remaining exact axes get the standalone pmean here.  Flat configs take
     the historical path op-for-op.
+
+    Device scopes (metadata only): ``grad_sync/b<j>`` around the bucket,
+    and under it one per step: ``pack``, ``exact`` (the pmeans), ``codec``
+    (the codec round, whose stages :mod:`repro.core.wire.base` names) and
+    ``unpack``.
     """
-    v = pack_bucket(grads, b)
-    if b.kind == "exact":
-        return unpack_bucket(jax.lax.pmean(v, b.eaxes), b, grads), ef
-    lcfg = _bucket_cfg(b, cmp, error_feedback=ef is not None)
-    pre = tuple(a for a in b.eaxes if a not in lcfg.inner_axes)
-    if pre:
-        v = jax.lax.pmean(v, pre)
-    kb = jax.random.fold_in(key, j)
-    if ef is not None:
-        v, e = coll.compressed_mean_stateful(v, ef, kb, lcfg)
-        return unpack_bucket(v, b, grads), e
-    v = coll.compressed_mean(v, kb, lcfg)
-    return unpack_bucket(v, b, grads), None
+    with jax.named_scope("grad_sync"), jax.named_scope(f"b{j}"):
+        with jax.named_scope("pack"):
+            v = pack_bucket(grads, b)
+        if b.kind == "exact":
+            with jax.named_scope("exact"):
+                v = jax.lax.pmean(v, b.eaxes)
+            with jax.named_scope("unpack"):
+                return unpack_bucket(v, b, grads), ef
+        lcfg = _bucket_cfg(b, cmp, error_feedback=ef is not None)
+        pre = tuple(a for a in b.eaxes if a not in lcfg.inner_axes)
+        if pre:
+            with jax.named_scope("exact"):
+                v = jax.lax.pmean(v, pre)
+        kb = jax.random.fold_in(key, j)
+        with jax.named_scope("codec"):
+            if ef is not None:
+                v, e = coll.compressed_mean_stateful(v, ef, kb, lcfg)
+            else:
+                v, e = coll.compressed_mean(v, kb, lcfg), None
+        with jax.named_scope("unpack"):
+            return unpack_bucket(v, b, grads), e
 
 
 def sync_grads_bucketed(grads: Mapping[str, jax.Array], plan: BucketPlan,

@@ -73,29 +73,45 @@ class Trainer:
         return start, params, opt_state, ef
 
     def fit(self):
+        """Train from the newest checkpoint (or step 0) to ``tcfg.steps``.
+
+        Each iteration is a profiler step (``train``) holding the host
+        spans ``feed``, ``dispatch``, ``metrics_sync`` (the host reads the
+        log step's metrics) and ``checkpoint``; they are recorded only
+        while the profiler is on (``jax.profiler.trace``), on the
+        clock of its device planes.
+        """
         start, params, opt_state, ef = self.init_or_restore()
         t0 = time.time()
         for step in range(start, self.tcfg.steps):
-            batch = self.data.device_batch(step, self.mesh, self.bspecs)
-            params, opt_state, ef, metrics = self.step_fn(
-                params, opt_state, ef, batch, jnp.int32(step))
-            if (step + 1) % self.tcfg.log_every == 0 or step == start:
-                m = {k: float(v) for k, v in metrics.items()}
-                m["step"] = step
-                m["sec"] = time.time() - t0
-                self.metrics_history.append(m)
-                log.info("step %d loss %.4f gnorm %.3f", step, m["loss"],
-                         m["grad_norm"])
-            if (self.tcfg.ckpt_dir
-                    and (step + 1) % self.tcfg.ckpt_every == 0):
-                self.ckpt.save(self.tcfg.ckpt_dir, step + 1, params,
-                               opt_state, self.specs,
-                               extra={"arch": self.cfg.name},
-                               keep_last=self.tcfg.keep_last)
-        self.ckpt.wait()
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with jax.profiler.TraceAnnotation("feed"):
+                    batch = self.data.device_batch(step, self.mesh,
+                                                   self.bspecs)
+                with jax.profiler.TraceAnnotation("dispatch"):
+                    params, opt_state, ef, metrics = self.step_fn(
+                        params, opt_state, ef, batch, jnp.int32(step))
+                if (step + 1) % self.tcfg.log_every == 0 or step == start:
+                    with jax.profiler.TraceAnnotation("metrics_sync"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    m["step"] = step
+                    m["sec"] = time.time() - t0
+                    self.metrics_history.append(m)
+                    log.info("step %d loss %.4f gnorm %.3f", step, m["loss"],
+                             m["grad_norm"])
+                if (self.tcfg.ckpt_dir
+                        and (step + 1) % self.tcfg.ckpt_every == 0):
+                    with jax.profiler.TraceAnnotation("checkpoint"):
+                        self.ckpt.save(self.tcfg.ckpt_dir, step + 1, params,
+                                       opt_state, self.specs,
+                                       extra={"arch": self.cfg.name},
+                                       keep_last=self.tcfg.keep_last)
+        with jax.profiler.TraceAnnotation("checkpoint"):
+            self.ckpt.wait()
+            if self.tcfg.ckpt_dir:
+                ckpt.save(self.tcfg.ckpt_dir, self.tcfg.steps, params,
+                          opt_state, self.specs,
+                          extra={"arch": self.cfg.name},
+                          keep_last=self.tcfg.keep_last)
         self.ef_state = ef
-        if self.tcfg.ckpt_dir:
-            ckpt.save(self.tcfg.ckpt_dir, self.tcfg.steps, params, opt_state,
-                      self.specs, extra={"arch": self.cfg.name},
-                      keep_last=self.tcfg.keep_last)
         return params, opt_state, self.metrics_history

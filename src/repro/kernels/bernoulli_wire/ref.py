@@ -185,9 +185,11 @@ def decode_sum_shard(bufs, mus, sent, prior, cap: int):
 
 def decode_sum_sequential(bufs, mus, keys, p: float, cap: int, d: int):
     """Peer-sequential Σ_i reconstruction_i — the fused decode kernel's
-    exact accumulation order (peer-major fori), used as its oracle."""
+    exact accumulation order (peer 0's reconstruction, then peer-major
+    adds), used as its oracle."""
     def body(i, acc):
-        return acc + decode_one(bufs[i], keys[i], p, cap, mus[i], d)
+        recon = decode_one(bufs[i], keys[i], p, cap, mus[i], d)
+        return jnp.where(i == 0, recon, acc + recon)
 
     return jax.lax.fori_loop(0, bufs.shape[0], body,
                              jnp.zeros((d,), jnp.float32))

@@ -27,7 +27,7 @@ import pytest
 
 from repro.core import comm_cost, types as t
 from repro.core.wire import codecs
-from repro.kernels.bernoulli_wire import ops, ref
+from repro.kernels.bernoulli_wire import kernel, ops, ref
 from repro.kernels.threefry import ref as tf_ref
 
 try:
@@ -79,9 +79,16 @@ def test_rank_select_equals_legacy_scatter(d, cap_frac):
 # Encode kernel vs oracle (interpret mode).
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("d", DIMS)
-@pytest.mark.parametrize("p", P_POW2)
-@pytest.mark.parametrize("scaled", (True, False))
+# one grid step of the kernels at full length: MAX_SUB sub-blocks of 1024.
+# Cases at a step less one and a step and one below; three steps and one in
+# test_shard_decode_exact_off_the_grid_step.
+STEP = kernel.MAX_SUB * kernel.BM
+
+
+@pytest.mark.parametrize(
+    "scaled,p,d",
+    [(s, p, d) for s in (True, False) for p in P_POW2 for d in DIMS]
+    + [(True, 0.0625, STEP - 1), (False, 0.0625, STEP + 1)])
 def test_encode_kernel_exact_for_pow2_inv_p(d, p, scaled):
     cap = comm_cost.bernoulli_capacity(d, p)
     flat = _flat(d, d)
@@ -129,8 +136,10 @@ def test_encode_cap_overflow_drops_tail_ranks():
 # Decode kernel vs oracle (interpret mode).
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("d", (1, 33, 1000, 1025, 4096))
-@pytest.mark.parametrize("n", (1, 3, 8))
+@pytest.mark.parametrize(
+    "n,d",
+    [(n, d) for n in (1, 3, 8) for d in (1, 33, 1000, 1025, 4096)]
+    + [(3, STEP + 1)])
 def test_decode_kernel_exact_vs_sequential_oracle(d, n):
     p = 0.0625 if d > 64 else 0.5
     cap = comm_cost.bernoulli_capacity(d, p)
@@ -147,6 +156,93 @@ def test_decode_kernel_exact_vs_sequential_oracle(d, n):
     # merely allclose.
     batched = ref.decode_sum(bufs, mus, keys, p, cap, d)
     np.testing.assert_array_equal(np.asarray(batched), np.asarray(want))
+
+
+# --------------------------------------------------------------------------- #
+# Every f32 bit pattern; shards off the grid step; cap inside a step.
+# --------------------------------------------------------------------------- #
+
+# -0.0, subnormals, ±inf, quiet and signalling NaNs with payloads, the
+# largest finite values, and values with four nonzero bytes
+SPECIAL_BITS = np.array(
+    [0x80000000, 0x00000001, 0x807FFFFF, 0x7F800000, 0xFF800000,
+     0x7FC12345, 0xFFE54321, 0x7FA00001, 0x7F7FFFFF, 0xFF7FFFFF,
+     0x3F8F1F2F, 0xC1A2B3C4], np.uint32)
+
+
+def _special_flat(d):
+    """Normal draws with every third coordinate one of SPECIAL_BITS."""
+    out = np.asarray(_flat(d, d)).copy()
+    slots = out[::3].view(np.uint32)     # a view: writes land in ``out``
+    slots[:] = np.resize(SPECIAL_BITS, slots.shape)
+    return jnp.asarray(out)
+
+
+# the oracles of the long cases, jitted (run eagerly they compile op by op)
+_ref_encode = jax.jit(ref.encode, static_argnums=(2, 3),
+                      static_argnames=("scaled",))
+_ref_support = jax.jit(ref.support_shard, static_argnums=(1, 2, 4))
+_ref_shard_sum = jax.jit(ref.decode_sum_shard, static_argnums=(4,))
+
+
+def _assert_bits(got, want):
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+@pytest.mark.parametrize("d", (5000,))
+def test_kernels_exact_on_every_f32_bit_pattern(d):
+    """Unscaled values (the EF twin) pass encode and both decodes bit for
+    bit whatever their bits: the compaction moves bytes and never adds or
+    multiplies a value, and one peer's decode is written, not added to
+    0.0 (which would turn -0.0 into +0.0).  d = 5000 walks two grid steps
+    of four sub-blocks."""
+    n, p = 1, 0.5
+    cap = comm_cost.bernoulli_capacity(d, p)
+    flat, mus = _special_flat(d), jnp.asarray([0.25], jnp.float32)
+    keys = jax.random.key_data(_key(d)).reshape(1, 2)
+    want = _ref_encode(flat, _key(d), p, cap, mus[0], scaled=False)
+    _assert_bits(ops.encode(flat, _key(d), p, cap, mus[0], scaled=False,
+                            force_pallas=True), want)
+    bufs = want.reshape(1, cap)
+    assert set(SPECIAL_BITS) <= set(np.asarray(want).view(np.uint32))
+    _assert_bits(ops.decode_sum(bufs, mus, keys, p, cap, d,
+                                force_pallas=True),
+                 ref.decode_sum_sequential(bufs, mus, keys, p, cap, d))
+    sent = _ref_support(keys, p, d, 0, d)
+    prior = jnp.zeros((n,), jnp.int32)
+    _assert_bits(ops.decode_sum_shard(bufs, mus, keys, sent, prior, 0, p=p,
+                                      cap=cap, d=d, force_pallas=True),
+                 _ref_shard_sum(bufs, mus, sent, prior, cap))
+
+
+@pytest.mark.parametrize("start,ds,cap_at", [
+    (2 * STEP + 100, STEP + 5, 2 * STEP + 16 * kernel.BM + 500),
+])
+def test_shard_decode_exact_off_the_grid_step(start, ds, cap_at):
+    """A shard that starts off every step, sub-block and lane boundary,
+    with each peer's support count before it as ``prior``, over two grid
+    steps; the second runs past d, and its cap is reached at ``cap_at``,
+    mid sub-block inside a grid step, so encode drops the tail ranks there
+    (the overflow path) and the shard decodes them to μ."""
+    d, p, n = 3 * STEP + 1, 0.0625, 2
+    keys = jnp.stack([jax.random.key_data(_key(d, i)) for i in range(n)])
+    full = _ref_support(keys, p, d, 0, d)
+    cap = int(jnp.min(jnp.sum(full[:, :cap_at], axis=1)))
+    mus = jnp.stack([jnp.mean(_flat(d + i, d)) for i in range(n)])
+    bufs = []
+    for i in range(n):
+        want = _ref_encode(_flat(d + i, d), keys[i], p, cap, mus[i])
+        _assert_bits(ops.encode(_flat(d + i, d), keys[i], p, cap, mus[i],
+                                force_pallas=True), want)
+        bufs.append(want)
+    bufs = jnp.stack(bufs)
+    prior = jnp.sum(full[:, :start], axis=1).astype(jnp.int32)
+    sent = _ref_support(keys, p, d, jnp.int32(start), ds)
+    got = ops.decode_sum_shard(bufs, mus, keys, sent, prior,
+                               jnp.int32(start), p=p, cap=cap, d=d,
+                               force_pallas=True)
+    _assert_bits(got, _ref_shard_sum(bufs, mus, sent, prior, cap))
 
 
 # --------------------------------------------------------------------------- #

@@ -5,7 +5,9 @@ TPU compiler refuses: reshapes that split the lane axis, unsigned
 reductions, a block that overruns VMEM.  These tests compile each entry
 point at real size — d = 2^20, n = 8 peers for the decodes, p = 1/16 — for
 a described v5e (no chip needed) and assert that the Pallas kernel is in
-the compiled program (``tpu_custom_call``).
+the compiled program (``tpu_custom_call``).  The Bernoulli kernels also
+compile at the length of the benchmark's largest Bernoulli bucket, where
+their resident wire buffer and widest grid step are largest.
 
 The topology and everything built from it live in module-scoped fixtures:
 the TPU library may be loaded by one process at a time, so nothing here
@@ -21,6 +23,10 @@ import pytest
 D = 1 << 20
 N = 8
 P_SEND = 1.0 / 16
+# the largest compressed bucket of the benchmark cell
+# mamba2-370m.train.bernoulli_seed_1bit: one 48-layer (1024 × 2048) leaf
+# (w_out, w_x, w_z), synced on one chip (n = 1, one shard of length D_CELL)
+D_CELL = 48 * 2048 * 1024
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +66,7 @@ def _entry(name):
     from repro.kernels.rotated_encode import kernel as rek
 
     cap = comm_cost.bernoulli_capacity(D, P_SEND)
+    cap_cell = comm_cost.bernoulli_capacity(D_CELL, P_SEND)
     f32, u32, i32 = jnp.float32, jnp.uint32, jnp.int32
     table = {
         "bernoulli_encode": (
@@ -73,6 +80,20 @@ def _entry(name):
             lambda b, m, k, pr, s: bwk.decode_sum_shard_pallas(
                 b, m, k, pr, s, p=P_SEND, cap=cap, d=D, ds=D // N),
             [((N, cap), f32), ((N,), f32), ((N, 2), u32), ((N,), i32),
+             ((), i32)]),
+        "bernoulli_encode_cell": (
+            lambda x, k, m: bwk.encode_pallas(x, k, m, p=P_SEND,
+                                              cap=cap_cell),
+            [((D_CELL,), f32), ((2,), u32), ((), f32)]),
+        "bernoulli_decode_sum_cell": (
+            lambda b, m, k: bwk.decode_sum_pallas(b, m, k, p=P_SEND,
+                                                  cap=cap_cell, d=D_CELL),
+            [((1, cap_cell), f32), ((1,), f32), ((1, 2), u32)]),
+        "bernoulli_decode_sum_shard_cell": (
+            lambda b, m, k, pr, s: bwk.decode_sum_shard_pallas(
+                b, m, k, pr, s, p=P_SEND, cap=cap_cell, d=D_CELL,
+                ds=D_CELL),
+            [((1, cap_cell), f32), ((1,), f32), ((1, 2), u32), ((1,), i32),
              ((), i32)]),
         "pack_bits_w1": (functools.partial(bpk.pack_bits_2d, width=1),
                          [((D // 128, 128), u32)]),
@@ -103,7 +124,9 @@ def _entry(name):
 ENTRIES = ("bernoulli_encode", "bernoulli_decode_sum",
            "bernoulli_decode_sum_shard", "pack_bits_w1", "pack_bits_w2",
            "unpack_bits_w1", "unpack_bits_w2", "binary_accum",
-           "rotated_encode_pack", "rotate_minmax", "fwht", "fixed_k_gather")
+           "rotated_encode_pack", "rotate_minmax", "fwht", "fixed_k_gather",
+           "bernoulli_encode_cell", "bernoulli_decode_sum_cell",
+           "bernoulli_decode_sum_shard_cell")
 
 
 @pytest.mark.parametrize("name", ENTRIES)

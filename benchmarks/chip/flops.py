@@ -1,27 +1,10 @@
-"""Model FLOPs of a Mamba-2 training step, from the configuration's shapes.
-
-Counted per token, forward and backward (backward = twice forward), with no
-recomputation: the dense projections (z, x, B, C, dt in; out), the tied
-head, and the SSD chunk terms (the chunk's C.B scores, their product with
-x, the chunk-state build and its read-out). The intra-chunk products are
-counted over the whole chunk block, as the chunked algorithm computes them.
-Norms, the depthwise convolution, gates and the loss's elementwise work are
-left out.
-"""
+"""Model FLOPs of a training step per token: forward and backward (backward
+= twice forward), with no recomputation. The forward count is the
+architecture's (``arch/<architecture>/flops.py``)."""
 from __future__ import annotations
 
-from benchmarks.chip import weights
-
-
-def forward_per_token(cfg: dict, seq_len: int) -> float:
-    k = weights.dims(cfg)
-    d, din, n, h, p = k["d"], k["din"], k["N"], k["H"], k["P"]
-    q = min(k["Q"], seq_len)
-    proj = 2 * d * (2 * din + 2 * n + h) + 2 * din * d
-    ssd = 2 * q * n + 2 * q * h * p + 4 * h * p * n
-    head = 2 * d * k["V"]
-    return float(k["L"] * (proj + ssd) + head)
+from benchmarks.chip import arch
 
 
 def train_per_token(cfg: dict, seq_len: int) -> float:
-    return 3.0 * forward_per_token(cfg, seq_len)
+    return 3.0 * arch.part(cfg, "flops").forward_per_token(cfg, seq_len)

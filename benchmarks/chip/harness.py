@@ -2,8 +2,10 @@
 reference, and the timed window.
 
 Everything a cell is comes from files found by name: the cell's entry in
-``BENCHMARK.json`` names its configuration (``configs/<config>.json``) and
-its traffic (``workloads/<traffic>.json``); each per-layer metric is read by
+``BENCHMARK.json`` names its configuration (``configs/<config>.json``, whose
+``architecture`` key names ``arch/<architecture>/``: the program's
+``ArchConfig``, the leaves, the reference model and the FLOP count) and its
+traffic (``workloads/<traffic>.json``); each per-layer metric is read by
 ``metrics/<metric>.py``; the limits of the comparison are
 ``limits/<cell>.json``; the device peaks are ``peaks.json``.
 
@@ -34,8 +36,8 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from benchmarks.chip import compare, flops, reference, trace_reduce, traffic
-from benchmarks.chip import weights
+from benchmarks.chip import arch, compare, flops, reference, scopes
+from benchmarks.chip import trace_reduce, traffic, weights
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -89,27 +91,13 @@ class Program:
     """The program's Trainer for a cell, with placements for its state."""
 
     def __init__(self, cell: Cell, devices):
-        from repro.configs.base import ArchConfig, RunConfig, ShapeSpec
+        from repro.configs.base import RunConfig, ShapeSpec
         from repro.core import types as ct
-        from repro.models.ssm import SSMCfg
         from repro.optim.optimizers import AdamWConfig
         from repro.train.trainer import Trainer, TrainerConfig
 
         c, t = cell.config, cell.traffic
-        fixed = {"architecture": "mamba2", "conv_bias": False,
-                 "residual_in_fp32": False, "compute_dtype": "bfloat16",
-                 "param_dtype": "float32", "tie_embeddings": True}
-        for k, v in fixed.items():
-            if c[k] != v:
-                raise ValueError(f"{c['name']}: the program runs {k}={v} only")
-        arch = ArchConfig(
-            name=c["name"], family="ssm", num_layers=c["n_layer"],
-            d_model=c["d_model"], num_heads=1, num_kv_heads=1,
-            head_dim=c["headdim"], d_ff=0, vocab_size=c["vocab_size"],
-            tie_embeddings=True, sub_quadratic=True,
-            ssm=SSMCfg(d_state=c["d_state"], head_dim=c["headdim"],
-                       expand=c["expand"], n_groups=c["ngroups"],
-                       conv_width=c["d_conv"], chunk=c["chunk_size"]))
+        arch_cfg = arch.part(c, "program").arch_config(c)
         cc = dict(t["compression"])
         comp = ct.CompressionConfig(
             encoder=ct.EncoderSpec(**cc.pop("encoder")),
@@ -124,7 +112,7 @@ class Program:
                                   devices=devices[:cell.chips])
         shape = ShapeSpec(cell.name, "train", t["seq_len"],
                           t["batch_per_chip"] * cell.chips)
-        self.trainer = Trainer(self.mesh, arch, run, shape,
+        self.trainer = Trainer(self.mesh, arch_cfg, run, shape,
                                TrainerConfig(seed=traffic.PROGRAM_SEED),
                                AdamWConfig(**t["optimizer"]))
         self.cfg = c
@@ -248,6 +236,7 @@ class Reading:
     flops_per_token: float
     chips: int
     peak: dict
+    scopes: dict = dataclasses.field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -324,12 +313,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     failed = int(np.sum(~np.isfinite(window_losses)))
     log(f"window done: {steps} steps in {elapsed:.3f} s", t_start, used)
 
-    classes = None
+    classes = smap = None
     if trace:
         b = prog.put(ring[0])
-        classes = trace_reduce.classify_hlo(
-            prog.trainer.step_fn.lower(*state, b, np.int32(0))
-            .compile().as_text())
+        hlo = prog.trainer.step_fn.lower(*state, b, np.int32(0)) \
+            .compile().as_text()
+        classes, smap = trace_reduce.classify_hlo(hlo), scopes.scope_map(hlo)
+        del hlo
     del state, losses, prog
     gc.collect()
 
@@ -348,7 +338,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         r = Reading(tr, classes, steps, tokens / elapsed,
                     flops.train_per_token(cell.config, t["seq_len"]),
-                    cell.chips, peak)
+                    cell.chips, peak, smap)
         metrics = {}
         for m in cell.per_layer:
             v = read_metric(m["name"], r)

@@ -1,5 +1,5 @@
-"""Plain float32 reference for a training cell: Mamba-2 loss and gradients,
-the gradient sync the cell names, gradient clipping and AdamW.
+"""Plain float32 reference for a training cell: the model's loss and
+gradients, the gradient sync the cell names, gradient clipping and AdamW.
 
 It imports nothing of the program and takes nothing the program made: the
 weights come from :mod:`benchmarks.chip.weights`, the rows from
@@ -7,24 +7,10 @@ weights come from :mod:`benchmarks.chip.weights`, the rows from
 from the seed with ``jax.random`` in its legacy (non-partitionable) Threefry
 layout, which is the stream the program's codecs are defined by.
 
-Layer equations (per row; ``x`` is the residual stream, f32):
-
-    h  = rmsnorm(x) * norm1
-    z, u, B, C, dt_raw = h W_z, h W_x, h W_B, h W_C, h W_dt
-    u, B, C = silu(causal_conv(u)), silu(causal_conv(B)), silu(causal_conv(C))
-    dt = softplus(dt_raw + dt_bias);  log_a = -dt * exp(A_log)
-    y_t = sum_{s<=t} exp(sum_{s<v<=t} log_a_v) dt_s (C_t . B_s) u_s + D u_t
-    x  = x + rmsnorm(y * silu(z)) * norm  W_out
-
-then ``rmsnorm(x) * final_norm``, logits against the tied embedding, and the
-token cross-entropy summed and divided by the global token count. The scan
-is evaluated chunk by chunk (``REF_CHUNK`` positions, not the program's
-chunk) with the carried state between chunks. Matmuls run at ``highest``
-precision. The ``int8`` policy is the control, one precision step below
-the configuration's bfloat16 and the one the chip's int8 peak (twice its
-bf16 peak) tempts: every dense projection and the head run as int8
-training matmuls (operands and the gradient flowing back rounded to int8
-under per-tensor scales).
+The model is the architecture's (``arch/<architecture>/model.py``): the
+summed token loss of a row, divided here by the global token count. Under
+the ``int8`` policy (the control, :mod:`benchmarks.chip.nn`) its dense
+projections and head run as int8 training matmuls.
 
 The sync follows the configuration's compression settings: leaves sorted by
 name and packed first-fit into buckets (leaves under ``min_compress_size``
@@ -51,143 +37,20 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from benchmarks.chip import weights
+from benchmarks.chip import arch, weights
+from benchmarks.chip.nn import ein
 
-HIGHEST = jax.lax.Precision.HIGHEST
-REF_CHUNK = 64
-CE_CHUNK = 512
 ROTATION_TAG = 0x524F54
 FWHT_BLOCK = 1 << 20
 
 
 # --------------------------------------------------------------------------- #
-# Model.
+# Loss and gradients.
 # --------------------------------------------------------------------------- #
-
-def _int8(x):
-    """x rounded to int8 under a symmetric per-tensor scale."""
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 127.0
-    return jnp.round(x / scale).astype(jnp.int8).astype(jnp.float32) * scale
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def int8_einsum(spec, a, b):
-    """An int8 training matmul: both operands, and the gradient flowing
-    back, rounded to int8 with per-tensor scales; products and sums exact
-    in float32."""
-    return ein(spec, _int8(a), _int8(b))
-
-
-def _int8_fwd(spec, a, b):
-    qa, qb = _int8(a), _int8(b)
-    return ein(spec, qa, qb), (qa, qb)
-
-
-def _int8_bwd(spec, res, g):
-    _, vjp = jax.vjp(functools.partial(ein, spec), *res)
-    return vjp(_int8(g))
-
-
-int8_einsum.defvjp(_int8_fwd, _int8_bwd)
-
-
-def dense(policy: str):
-    """The einsum the dense projections and the head use under ``policy``."""
-    if policy == "f32":
-        return ein
-    if policy == "int8":
-        return int8_einsum
-    raise ValueError(f"unknown precision policy {policy!r}")
-
-
-def ein(spec, *ops):
-    return jnp.einsum(spec, *ops, precision=HIGHEST)
-
-
-def rmsnorm(x, w, eps=1e-6):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def conv_silu(x, w):
-    """Depthwise causal convolution over positions, then silu. x: (s, c)."""
-    s, width = x.shape[0], w.shape[0]
-    xp = jnp.concatenate([jnp.zeros((width - 1, x.shape[1]), x.dtype), x])
-    y = sum(xp[i:i + s] * w[i] for i in range(width))
-    return jax.nn.silu(y)
-
-
-def ssd(u, B, C, dt, log_a, q):
-    """u: (s, h, p); B, C: (s, n); dt, log_a: (s, h) -> (s, h, p)."""
-    s, h, p = u.shape
-    q = min(q, s)
-    nc = s // q
-    uc = u.reshape(nc, q, h, p)
-    Bc, Cc = B.reshape(nc, q, -1), C.reshape(nc, q, -1)
-    dtc, cum = dt.reshape(nc, q, h), jnp.cumsum(log_a.reshape(nc, q, h), 1)
-    tril = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None]
-    seg = jnp.where(tril, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf)
-    G = ein("ctn,cun->ctu", Cc, Bc)
-    y_in = ein("ctuh,cuhp->cthp", G[..., None] * jnp.exp(seg) * dtc[:, None],
-               uc)
-    to_end = jnp.exp(cum[:, -1:, :] - cum) * dtc                 # (nc, q, h)
-    s_loc = ein("cuh,cuhp,cun->chpn", to_end, uc, Bc)
-    decay = jnp.exp(cum[:, -1, :])                               # (nc, h)
-
-    def carry(state, inp):
-        dc, sl = inp
-        return dc[:, None, None] * state + sl, state
-
-    _, s_in = jax.lax.scan(carry, jnp.zeros(s_loc.shape[1:], u.dtype),
-                           (decay, s_loc))
-    y_out = ein("ctn,cth,chpn->cthp", Cc, jnp.exp(cum), s_in)
-    return (y_in + y_out).reshape(s, h, p)
-
-
-def _layer(cfg, mm, x, p):
-    k = weights.dims(cfg)
-    s = x.shape[0]
-    h = rmsnorm(x, p["norm1"])
-    z = mm("sd,de->se", h, p["ssm.w_z"])
-    u = conv_silu(mm("sd,de->se", h, p["ssm.w_x"]), p["ssm.conv_x"])
-    B = conv_silu(mm("sd,dn->sn", h, p["ssm.w_B"]), p["ssm.conv_B"])
-    C = conv_silu(mm("sd,dn->sn", h, p["ssm.w_C"]), p["ssm.conv_C"])
-    dt = jax.nn.softplus(mm("sd,dh->sh", h, p["ssm.w_dt"]) + p["ssm.dt_bias"])
-    log_a = -dt * jnp.exp(p["ssm.A_log"])
-    u = u.reshape(s, k["H"], k["P"])
-    y = ssd(u, B, C, dt, log_a, REF_CHUNK) + u * p["ssm.D"][None, :, None]
-    y = rmsnorm(y.reshape(s, k["din"]) * jax.nn.silu(z), p["ssm.norm"])
-    return x + mm("se,ed->sd", y, p["ssm.w_out"])
-
-
-def row_loss(params, tokens, labels, mask, cfg, policy):
-    """Summed token cross-entropy of one row."""
-    mm = dense(policy)
-    layers = {k[len("layers."):]: v for k, v in params.items()
-              if k.startswith("layers.")}
-    x = params["embed"][tokens]
-    body = jax.checkpoint(lambda c, p: (_layer(cfg, mm, c, p), None))
-    x, _ = jax.lax.scan(body, x, layers)
-    h = rmsnorm(x, params["final_norm"])
-    s = h.shape[0]
-    ch = min(CE_CHUNK, s)
-
-    @jax.checkpoint
-    def ce(args):
-        hc, yc, mc = args
-        logits = mm("sd,vd->sv", hc, params["embed"])
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, yc[:, None], axis=-1)[:, 0]
-        return jnp.sum((lse - tgt) * mc)
-
-    parts = jax.lax.map(ce, (h.reshape(s // ch, ch, -1),
-                             labels.reshape(s // ch, ch),
-                             mask.reshape(s // ch, ch)))
-    return jnp.sum(parts)
-
 
 def node_loss_grad(params, batch, cfg, policy, global_tokens):
     """(loss, grads) of one node's rows, accumulated row by row."""
-    vg = jax.value_and_grad(row_loss)
+    vg = jax.value_and_grad(arch.part(cfg, "model").row_loss)
 
     def body(acc, row):
         l, g = vg(params, row["tokens"], row["labels"], row["mask"], cfg,

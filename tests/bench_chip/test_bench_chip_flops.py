@@ -27,10 +27,12 @@ def test_hand_count_mamba2_130m():
 
 
 @pytest.mark.parametrize("cfg", [
-    dict(n_layer=1, d_model=512, d_state=64, headdim=64, expand=2, d_conv=4,
-         ngroups=1, chunk_size=128, vocab_size=8192),
-    dict(n_layer=1, d_model=256, d_state=32, headdim=32, expand=2, d_conv=4,
-         ngroups=1, chunk_size=64, vocab_size=4096),
+    dict(architecture="mamba2", n_layer=1, d_model=512, d_state=64,
+         headdim=64, expand=2, d_conv=4, ngroups=1, chunk_size=128,
+         vocab_size=8192),
+    dict(architecture="mamba2", n_layer=1, d_model=256, d_state=32,
+         headdim=32, expand=2, d_conv=4, ngroups=1, chunk_size=64,
+         vocab_size=4096),
 ])
 def test_against_xla_cost_analysis(cfg):
     """One layer and one chunk, so that no loop hides its trip count from

@@ -15,7 +15,8 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[0:0] = [str(ROOT)]
 
-from benchmarks.chip import compare, harness, trace_reduce  # noqa: E402
+from benchmarks.chip import compare, harness, scopes  # noqa: E402
+from benchmarks.chip import trace_reduce  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 DATA = ROOT / "tests" / "bench_chip" / "data"
@@ -140,6 +141,34 @@ def test_result_line_keys(traced, cpu_peaks, monkeypatch, tmp_path):
         assert len(out["breakdown"]["device_ops"]) <= 10
     else:
         assert set(out["metrics"]) == {"train_tokens_per_s", "setup_s"}
+
+
+def test_traced_run_reads_the_scopes(cpu_peaks, monkeypatch, tmp_path):
+    """A traced run hands the per-layer readers the compiled step's scope
+    map; the scoped chip trace stands in for the CPU's, which has no TPU
+    planes."""
+    scoped = DATA / "scoped"
+    tag = "tiny.train.ef_rotated_binary"
+    classes = json.loads(
+        (scoped / f"{tag}.classes.json").read_text())["classes"]
+    xplane = tmp_path / f"{tag}.xplane.pb"
+    xplane.write_bytes(gzip.decompress(
+        (scoped / f"{tag}.xplane.pb.gz").read_bytes()))
+    tr = trace_reduce.reduce(xplane, classes)
+    smap = scopes.scope_map(gzip.decompress(
+        (scoped / f"{tag}.hlo.txt.gz").read_bytes()).decode())
+    monkeypatch.setattr(trace_reduce, "reduce", lambda p, c: tr)
+    monkeypatch.setattr(trace_reduce, "xplane_file", lambda d: None)
+    monkeypatch.setattr(trace_reduce, "classify_hlo", lambda t: classes)
+    monkeypatch.setattr(scopes, "scope_map", lambda t: smap)
+    per_layer = [("codec_kernel_ms", "ms/step"), ("codec_round_ms", "ms/step"),
+                 ("grad_sync_ms", "ms/step")]
+    out = harness.run(tiny_cell("train.ef_rotated_binary", per_layer),
+                      seed=2 ** 33 + 6, seconds=0.2, trace=True,
+                      devices=jax.devices(), t_start=0.0)
+    m = {k: v["value"] for k, v in out["metrics"].items()}
+    assert set(m) == {n for n, _ in per_layer}
+    assert 0 < m["codec_kernel_ms"] <= m["codec_round_ms"] <= m["grad_sync_ms"]
 
 
 def test_run_cell_refuses_cpu():
